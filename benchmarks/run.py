@@ -25,6 +25,9 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
     steps = 30 if args.fast else args.steps
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from . import (bench_backend_frontier, bench_conv_kernel,
                    bench_dequant_overhead, bench_drift_recal,
                    bench_granularity, bench_hw_cost, bench_kernel,

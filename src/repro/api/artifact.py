@@ -404,13 +404,17 @@ def pack_model(params: Dict, cfg: CIMConfig, *,
                 return {**extras, **pack_cv(layer, cfg, **kw)}
             if w.ndim in (3, 5):
                 pack = pack_lin if w.ndim == 3 else pack_cv
+                # one layer at a time: a vmap over the stack would hold
+                # every layer's float digit temporaries at once — several
+                # GiB per projection at published widths, past one chip's
+                # HBM
                 if vkey is None:
-                    packed = jax.vmap(lambda p: pack(p, cfg))(layer)
+                    packed = jax.lax.map(lambda p: pack(p, cfg), layer)
                 else:
                     keys = jax.random.split(vkey, w.shape[0])
-                    packed = jax.vmap(lambda p, k: pack(
-                        p, cfg, variation_key=k,
-                        variation_std=variation_std))(layer, keys)
+                    packed = jax.lax.map(lambda pk: pack(
+                        pk[0], cfg, variation_key=pk[1],
+                        variation_std=variation_std), (layer, keys))
                 return {**extras, **packed}
             raise ValueError(f"CIM layer at {'/'.join(path)} has "
                              f"unsupported weight rank {w.ndim}")

@@ -216,7 +216,10 @@ def _forward_conv_emulate(x, params, cfg, stride, padding, variation_key,
     s_w = t.broadcast_weight_scale(params["s_w"])            # (kt, co)
     places = place_values(cfg.weight_bits, cfg.cell_bits)    # (S,)
     deq = places[:, None, None] * s_w[None]                  # (S, kt, co)
-    y = jnp.einsum("bhwstc,stc->bhwc", psum.astype(jnp.float32), deq)
+    # float32 dequant sum: HIGHEST keeps TPU from feeding it to the MXU
+    # as bfloat16 (the deploy kernel accumulates it in float32)
+    y = jnp.einsum("bhwstc,stc->bhwc", psum.astype(jnp.float32), deq,
+                   precision=jax.lax.Precision.HIGHEST)
     y = y * jnp.maximum(s_a, 1e-9)
     return y.astype(compute_dtype)
 

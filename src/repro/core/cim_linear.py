@@ -322,7 +322,10 @@ def _forward_emulate(x, params, cfg, variation_key, sigma, compute_dtype):
     s_w = _full_weight_scale(params, t)                       # (kt, N)
     places = place_values(cfg.weight_bits, cfg.cell_bits)     # (S,)
     deq = (places[:, None, None] * s_w[None, :, :])           # (S, kt, N)
-    y = jnp.einsum("...stn,stn->...n", psum.astype(jnp.float32), deq)
+    # float32 dequant sum: HIGHEST keeps TPU from feeding it to the MXU
+    # as bfloat16 (the deploy kernel accumulates it in float32)
+    y = jnp.einsum("...stn,stn->...n", psum.astype(jnp.float32), deq,
+                   precision=jax.lax.Precision.HIGHEST)
     y = y * jnp.maximum(s_a, 1e-9)
     return y.astype(compute_dtype)
 

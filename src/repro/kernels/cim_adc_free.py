@@ -13,7 +13,7 @@ Relative to ``kernels/cim_matmul._kernel`` the body drops the ADC stage
 (round -> scale -> clip -> rescale in VMEM) *and* the s_p operand — the
 per-column ADC scale stream never leaves HBM because it does not exist
 on this hardware. Everything else is deliberately identical: same grid
-(M/bm, N/bn, k_tiles, n_split) with the reduction dims iterating
+(M/bm, N/bn, n_split, k_tiles) with the reduction dims iterating
 fastest, same packed digit-plane layout, same trailing-N column-shard
 contract (kernels/ops dispatches this kernel per column shard under
 shard_map unchanged, DESIGN.md §10), and cell variation is injected on
@@ -36,59 +36,26 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.nibble import unpack_nibbles
-from repro.core.variation import perturb_digits, variation_wanted
-
-from .cim_matmul import decode_digit_block
+from .cim_matmul import _block_psum, _zero_at_start, fused_grid_call
 from .ref import extract_conv_patches
 
 
-def _kernel(a_ref, d_ref, deq_ref, o_ref, *, nibble: bool = False,
-            groups: int = 1):
-    s = pl.program_id(2)
-    t = pl.program_id(3)
-
-    @pl.when(jnp.logical_and(t == 0, s == 0))
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    a = a_ref[:, 0, :].astype(jnp.float32)          # (bm, rows)
-    d = decode_digit_block(d_ref[0, 0], nibble=nibble, groups=groups)
-    p = jnp.dot(a, d, preferred_element_type=jnp.float32)  # (bm, bn)
+def _kernel(*refs, nibble: bool = False, groups: int = 1, table=None):
+    """Grid (i, j, s, t). With ``table`` (the occupancy skip) the first
+    ref is the scalar-prefetched block table: a dead block skips the MAC
+    (``cim_matmul._block_psum``). No sign-ADC subtlety exists here: an
+    all-zero plane's exact digital psum is 0, so dense and skip both add
+    +0.0 — bit-identical on a +0.0-initialized f32 accumulator."""
+    occ_ref = refs[0] if table is not None else None
+    a_ref, d_ref, deq_ref, o_ref = refs[-4:]
+    j, s, t = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    _zero_at_start(o_ref, t, s)
+    p = _block_psum(occ_ref, a_ref, d_ref, nibble=nibble, groups=groups,
+                    table=table, j=j, t=t, s=s)
     # digital accumulation: snap the integer-valued MACs (kills float
     # roundoff, matching the ADC kernel's pre-quantize snap) and add the
     # dequantized word straight into the accumulator — no ADC stage
-    p = jnp.round(p)
-    deq = deq_ref[0, 0, :].astype(jnp.float32)      # (bn,)
-    o_ref[...] += p * deq[None, :]
-
-
-def _kernel_sparse(a_ref, d_ref, occ_ref, deq_ref, o_ref, *,
-                   nibble: bool = False, groups: int = 1):
-    """Occupancy-aware ADC-free body: a (bn-column) block whose digit
-    planes are ALL unoccupied skips the MAC entirely; any occupied column
-    makes the block run the verbatim dense body (per-column masking
-    between multiply and accumulate perturbs XLA fusion at 1 ulp). No
-    compensation exists here (unlike the sign-ADC case): an all-zero
-    plane's exact digital psum is 0, so the dense path adds +0.0 and the
-    skip adds nothing — bit-identical on a +0.0-initialized f32
-    accumulator (round-to-nearest never produces -0.0 from +0.0)."""
-    s = pl.program_id(2)
-    t = pl.program_id(3)
-
-    @pl.when(jnp.logical_and(t == 0, s == 0))
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    occ = occ_ref[0, 0, :]                          # (bn,) uint8
-
-    @pl.when(jnp.any(occ > 0))
-    def _mac():
-        a = a_ref[:, 0, :].astype(jnp.float32)
-        d = decode_digit_block(d_ref[0, 0], nibble=nibble, groups=groups)
-        p = jnp.round(jnp.dot(a, d, preferred_element_type=jnp.float32))
-        deq = deq_ref[0, 0, :].astype(jnp.float32)
-        o_ref[...] += p * deq[None, :]
+    o_ref[...] += jnp.round(p) * deq_ref[0, 0, :].astype(jnp.float32)[None, :]
 
 
 @functools.partial(
@@ -110,66 +77,19 @@ def cim_matmul_adc_free_pallas(
 ) -> jnp.ndarray:
     """ADC-free CIM matmul: digital accumulation of bit-sliced psums.
 
-    Same operands as ``cim_matmul_pallas`` minus ``s_p`` (no ADC scale
-    stream exists on this hardware style). Returns (M, N) float32.
+    Same operands and tile-major layout as ``cim_matmul_pallas`` minus
+    ``s_p`` (no ADC scale stream exists on this hardware style). Returns
+    (M, N) float32.
     """
-    nibble = digits.dtype == jnp.uint8   # nibble-packed HBM planes (§14)
-    if variation_wanted(variation_key, variation_std):
-        # perturb BEFORE block padding: noise indices must match the
-        # packed (unpadded) LOGICAL layout the emulate path perturbs (§8)
-        if nibble:
-            digits = unpack_nibbles(digits, groups=nibble_groups)
-            nibble = False
-        digits = perturb_digits(digits, variation_key, variation_std)
-    m, k_tiles, rows = a_t.shape
-    n_split = digits.shape[0]
-    n = digits.shape[-1]
-    rows_d = digits.shape[2]             # stored rows: rows/2 when nibble
-    assert rows_d == (rows // 2 if nibble else rows), \
-        (digits.shape, a_t.shape, nibble)
-
-    bm = min(block_m, m)
-    bn = min(block_n, n)
-    pad_m = (-m) % bm
-    pad_n = (-n) % bn
-    if pad_m:
-        a_t = jnp.pad(a_t, ((0, pad_m), (0, 0), (0, 0)))
-    if pad_n:
-        digits = jnp.pad(digits, ((0, 0), (0, 0), (0, 0), (0, pad_n)))
-        deq = jnp.pad(deq, ((0, 0), (0, 0), (0, pad_n)))
-        if occ is not None:
-            occ = jnp.pad(occ, ((0, 0), (0, 0), (0, pad_n)))  # dead: skip
-    mp, np_ = m + pad_m, n + pad_n
-
-    # reduction dims (s outer, t inner): the digital accumulator adds the
-    # dequantized words in the SAME row-major (s, t) order the oracle's
-    # einsum reduction uses — unquantized psums carry full mantissas, so
-    # (unlike the ADC kernel's coarse post-quantization words) any
-    # reassociation here is visible at 1 ulp and amplifies through the
-    # next layer's activation-code rounding at model scale
-    grid = (mp // bm, np_ // bn, n_split, k_tiles)
-    col_spec = pl.BlockSpec((1, 1, bn), lambda i, j, s, t: (s, t, j))
-    in_specs = [
-        pl.BlockSpec((bm, 1, rows), lambda i, j, s, t: (i, t, 0)),
-        pl.BlockSpec((1, 1, rows_d, bn), lambda i, j, s, t: (s, t, 0, j)),
-    ]
-    if occ is None:
-        body = _kernel
-        args = (a_t, digits, deq)
-    else:
-        body = _kernel_sparse
-        args = (a_t, digits, occ.astype(jnp.uint8), deq)
-        in_specs.append(col_spec)
-    in_specs.append(col_spec)
-    out = pl.pallas_call(
-        functools.partial(body, nibble=nibble, groups=nibble_groups),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, s, t: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
-        interpret=interpret,
-    )(*args)
-    return out[:m, :n]
+    # reduction dims (s outer, t inner), iterating fastest: the digital
+    # accumulator adds the dequantized words in the order of the ADC
+    # kernel and of the oracle (``ref.shift_add``) — unquantized psums
+    # carry full mantissas, so any reassociation is visible at 1 ulp and
+    # amplifies through the next layer's activation-code rounding
+    return fused_grid_call(_kernel, a_t, digits, ((deq, 0.0),),
+                           variation_key, variation_std, occ,
+                           nibble_groups=nibble_groups, block_m=block_m,
+                           block_n=block_n, interpret=interpret)
 
 
 @functools.partial(

@@ -25,7 +25,7 @@ per-cell noise from a shared key; DESIGN.md §8.)
      kernel re-reads the same patch block per bit-split via its BlockSpec
      index map (the a-operand map ignores the split index).
   2. The spatial axis flattens to M = B*H'*W' and lowers onto the fused
-     CIM matmul kernel, whose grid (M/bm, C_out/bn, k_tiles, n_split)
+     CIM matmul kernel, whose grid (M/bm, C_out/bn, n_split, k_tiles)
      applies ADC quantization to each array-tile accumulator in VMEM —
      the partial-sum tensor never touches HBM (DESIGN.md §7).
 

@@ -1,8 +1,10 @@
 """Jitted public wrappers around the Pallas kernels.
 
-``use_kernel=True`` runs the Pallas kernel (interpret mode off-TPU so the
-kernel body is validated on CPU); ``use_kernel=False`` runs the pure-jnp
-oracle — used for allocation-free dry-runs where the HLO must be portable.
+``use_kernel=True`` runs the Pallas kernel — compiled by Mosaic on TPU, in
+interpret mode on the CPU backend so the tests validate the kernel body
+there (``interpret_mode``; any other backend is an error, never a silent
+fallback); ``use_kernel=False`` runs the pure-jnp oracle — used for
+allocation-free dry-runs where the HLO must be portable.
 
 Both wrappers accept ``variation_key``/``variation_std``: when set, the
 digit planes are evaluated under one Monte-Carlo realization of log-normal
@@ -53,8 +55,19 @@ from .cim_matmul import cim_matmul_experts_pallas, cim_matmul_pallas
 COL_SHARD_AXIS = "model"
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def interpret_mode() -> bool:
+    """Whether the kernel dispatches run Pallas in interpret mode: True on
+    the CPU backend (what the tests use), False on TPU. Any other backend
+    raises — the kernels are written for TPU, and quietly interpreting
+    them elsewhere would hide the device."""
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"the Pallas deploy kernels run on TPU (or interpreted on the "
+            f"CPU backend, for tests); the default backend is {backend!r}. "
+            f"Serve on TPU, or set JAX_PLATFORMS=cpu, or use the jnp "
+            f"oracle (CIMConfig(use_kernel=False) / the 'ref' backend).")
+    return backend == "cpu"
 
 
 def col_shards(mesh, mesh_axis: str = COL_SHARD_AXIS) -> int:
@@ -142,7 +155,6 @@ def _cim_matmul_sharded(
     n = digits.shape[-1]
     n_shards = mesh.shape[mesh_axis]
     digits, s_p, deq, occ = pad_cols(digits, s_p, deq, n_shards, occ)
-    interp = not _on_tpu()
 
     def local(a_, d_, sp_, dq_, *rest):
         occ_ = rest[0] if rest else None
@@ -153,7 +165,8 @@ def _cim_matmul_sharded(
                 out = cim_matmul_adc_free_pallas(
                     a_, d_, dq_, None, None, occ_,
                     nibble_groups=nibble_groups,
-                    block_m=block_m, block_n=block_n, interpret=interp)
+                    block_m=block_m, block_n=block_n,
+                    interpret=interpret_mode())
             else:
                 out = ref.cim_matmul_adc_free_ref(a_, d_, dq_)
         elif use_kernel:
@@ -161,7 +174,8 @@ def _cim_matmul_sharded(
                 a_, d_, sp_, dq_, None, None, occ_,
                 psum_bits=psum_bits, psum_quant=psum_quant,
                 nibble_groups=nibble_groups,
-                block_m=block_m, block_n=block_n, interpret=interp)
+                block_m=block_m, block_n=block_n,
+                interpret=interpret_mode())
         else:
             out = ref.cim_matmul_ref(a_, d_, sp_, dq_, psum_bits=psum_bits,
                                      psum_quant=psum_quant)
@@ -239,7 +253,7 @@ def cim_matmul(
         out = cim_matmul_adc_free_pallas(
             a2, digits, deq, variation_key, variation_std, occ,
             block_m=block_m, block_n=block_n,
-            interpret=not _on_tpu(),
+            interpret=interpret_mode(),
         )
     elif adc_free:
         if digits.dtype == jnp.uint8:
@@ -252,7 +266,7 @@ def cim_matmul(
             a2, digits, s_p, deq, variation_key, variation_std, occ,
             psum_bits=psum_bits, psum_quant=psum_quant,
             block_m=block_m, block_n=block_n,
-            interpret=not _on_tpu(),
+            interpret=interpret_mode(),
         )
     else:
         if digits.dtype == jnp.uint8:
@@ -280,7 +294,7 @@ def cim_matmul_experts(
     """Batched MoE expert-bank dispatch: every expert's capacity buffer
     through ONE kernel launch (expert index = leading grid dimension),
     bit-exact with ``lax.map`` of ``cim_matmul`` over experts — same
-    block shapes, same (t, s) accumulation order per output block.
+    block shapes, same (s, t) accumulation order per output block.
 
     The caller (``models.layers._expert_matmul``) gates this to the
     plain deploy fast path: single-device (no column-sharded mesh),
@@ -299,7 +313,7 @@ def cim_matmul_experts(
         a_t, digits, s_p, deq,
         psum_bits=psum_bits, psum_quant=psum_quant,
         block_m=block_m, block_n=block_n,
-        interpret=not _on_tpu(),
+        interpret=interpret_mode(),
     )
 
 
@@ -378,7 +392,7 @@ def cim_conv(
             kh=kh, kw=kw, stride=stride, padding=padding,
             c_per_array=c_per_array,
             block_m=block_m, block_n=block_n,
-            interpret=not _on_tpu(),
+            interpret=interpret_mode(),
         )
     if adc_free:
         if digits.dtype == jnp.uint8:
@@ -400,7 +414,7 @@ def cim_conv(
             c_per_array=c_per_array,
             psum_bits=psum_bits, psum_quant=psum_quant,
             block_m=block_m, block_n=block_n,
-            interpret=not _on_tpu(),
+            interpret=interpret_mode(),
         )
     if digits.dtype == jnp.uint8:
         digits = unpack_nibbles(digits, groups=kh * kw)

@@ -1,12 +1,19 @@
 """Pure-jnp oracles for the Pallas kernels.
 
 These define the exact arithmetic the kernels must reproduce; tests sweep
-shapes/dtypes and assert allclose against them.
+shapes/dtypes and assert allclose against them. The psum contractions
+ask for ``HIGHEST`` precision: TPU's default precision feeds float32
+operands to the MXU as bfloat16, exact for integer codes and digits but
+not for the float digits of a variation realization. (The CPU backend
+computes float32 either way.) The dequant sums run in the kernels' order
+(``shift_add``).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def adc_quantize_ref(p: jnp.ndarray, s_p: jnp.ndarray, psum_bits: int) -> jnp.ndarray:
@@ -22,6 +29,28 @@ def adc_quantize_ref(p: jnp.ndarray, s_p: jnp.ndarray, psum_bits: int) -> jnp.nd
     qn = -(2 ** (psum_bits - 1))
     qp = 2 ** (psum_bits - 1) - 1
     return jnp.clip(jnp.round(p / s_p), qn, qp) * s_p
+
+
+def shift_add(psum: jnp.ndarray, deq: jnp.ndarray) -> jnp.ndarray:
+    """Dequantize and accumulate the column psums, (M, S, k_tiles, N) x
+    (S, k_tiles, N) -> (M, N), one (split, tile) term at a time in the
+    deploy kernels' grid order: bit split outer, array tile inner — the
+    row-major (s, t) order the emulate path's einsum takes on the CPU at
+    the tests' shapes.
+
+    Float addition is not associative. An einsum leaves the order to the
+    compiler, and on TPU its order differs from the kernel's in the last
+    bit of about three outputs in four; the next layer's activation
+    rounding turns those bits into different codes, and a deep model's
+    logits into different ones."""
+    m, n = psum.shape[0], psum.shape[-1]
+    p = jnp.transpose(psum, (1, 2, 0, 3)).reshape(-1, m, n)
+    d = deq.astype(jnp.float32).reshape(-1, n)
+
+    def add(y, term):
+        p_i, d_i = term
+        return y + p_i * d_i[None, :], None
+    return jax.lax.scan(add, jnp.zeros((m, n), jnp.float32), (p, d))[0]
 
 
 def cim_matmul_ref(
@@ -41,10 +70,11 @@ def cim_matmul_ref(
         a_t.astype(jnp.float32),
         digits.astype(jnp.float32),
         preferred_element_type=jnp.float32,
+        precision=HIGHEST,
     )
     if psum_quant:
         psum = adc_quantize_ref(psum, s_p[None], psum_bits)
-    return jnp.einsum("mstn,stn->mn", psum, deq.astype(jnp.float32))
+    return shift_add(psum, deq)
 
 
 def cim_matmul_adc_free_ref(
@@ -61,9 +91,10 @@ def cim_matmul_adc_free_ref(
         a_t.astype(jnp.float32),
         digits.astype(jnp.float32),
         preferred_element_type=jnp.float32,
+        precision=HIGHEST,
     )
     psum = jnp.round(psum)  # same integer snap as the ADC oracle
-    return jnp.einsum("mstn,stn->mn", psum, deq.astype(jnp.float32))
+    return shift_add(psum, deq)
 
 
 def lsq_fake_quant_ref(x, s, qn: float, qp: float):

@@ -39,7 +39,7 @@ import jax
 import numpy as np
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -83,8 +83,13 @@ def main(argv=None):
                     help="arm the per-column ADC saturation collector, "
                          "folding every Nth kernel invocation (0 = off; "
                          "DESIGN.md §12)")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+def build_engine(args: argparse.Namespace):
+    """The engine ``main`` serves from, and the model config it serves:
+    with ``--cim deploy`` (or ``--artifact``) a packed ``DeployArtifact``
+    on the fused Pallas deploy kernels."""
     from repro.configs.registry import get_config
     from repro.core.cim_linear import CIMConfig
     from repro.core.variation import DriftSchedule
@@ -131,7 +136,7 @@ def main(argv=None):
         # QAT-shaped config; deploy serving packs these params below
         cim = CIMConfig(enabled=True, mode="emulate", weight_bits=4,
                         cell_bits=2, act_bits=8, psum_bits=6,
-                        array_rows=128, array_cols=128, use_kernel=False)
+                        array_rows=128, array_cols=128)
     cfg = get_config(args.arch, reduced=args.reduced, cim=cim)
 
     if args.artifact is not None:
@@ -162,6 +167,14 @@ def main(argv=None):
                                temperature=args.temperature, seed=args.seed,
                                **drift_kw)
     engine.t = args.drift_t0
+    return engine, cfg
+
+
+def main(argv=None):
+    from repro.launch.compile_cache import enable_compile_cache
+    args = parse_args(argv)
+    enable_compile_cache()
+    engine, cfg = build_engine(args)
     rng = np.random.RandomState(args.seed)
     prompts = rng.randint(0, cfg.vocab, size=(args.batch, args.prompt_len)
                           ).astype(np.int32)
@@ -169,8 +182,7 @@ def main(argv=None):
     out = engine.generate_batch(prompts, args.new_tokens)
     dt = time.time() - t0
     n_new = out.shape[0] * out.shape[1]
-    devs = args.mesh if mesh is not None else 1
-    print(f"[serve] arch={args.arch} mesh={devs} generated {out.shape} "
+    print(f"[serve] arch={args.arch} mesh={args.mesh} generated {out.shape} "
           f"tokens in {dt:.2f}s ({n_new / dt:.1f} tok/s)")
     print(f"[serve] sample continuation: {out[0][:16].tolist()}")
     h = engine.health()

@@ -171,17 +171,10 @@ def session_mesh(mesh, rules: Optional[Dict[str, Any]] = None):
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """Version-compat shard_map: newer jax exposes ``jax.shard_map`` with
-    ``check_vma``; 0.4.x has ``jax.experimental.shard_map.shard_map`` with
-    the same flag spelled ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
+    """``jax.shard_map``, with ``check_vma`` passed only when given."""
+    kw = {} if check_vma is None else {"check_vma": check_vma}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
 def current_rules() -> Dict[str, Any]:

@@ -528,3 +528,36 @@ class ServingEngine:
             self._observe_health(stats)
             self._maybe_report()
         return np.concatenate(outs, axis=1)
+
+    # -- inspection: the exact programs generate_batch runs --------------------
+    def prefill_logits(self, prompts: np.ndarray) -> np.ndarray:
+        """Logits (B, T, V) of a prefill over ``prompts`` (B, T) on a fresh
+        cache, through the engine's own jitted prefill — a parity check
+        compares exactly the program that serves."""
+        self._check_mesh("prefill_logits")
+        cache = self.model.init_cache(self.cfg, self.B, self.max_len)
+        logits, _ = self._prefill_fn(self.params, cache, jnp.asarray(prompts),
+                                     jnp.int32(self.t))
+        return np.asarray(logits.astype(jnp.float32))
+
+    def decode_logits(self, prompts: np.ndarray, tokens: np.ndarray
+                      ) -> np.ndarray:
+        """Logits (B, V) of one decode step over ``tokens`` (B, 1) after a
+        prefill of ``prompts``: the decode-shaped model call, teacher-
+        forced, so two engines can be compared on the same inputs."""
+        self._check_mesh("decode_logits")
+        cache = self.model.init_cache(self.cfg, self.B, self.max_len)
+        t = jnp.int32(self.t)
+        _, cache = self._prefill_fn(self.params, cache, jnp.asarray(prompts), t)
+        logits, _ = self._prefill_fn(self.params, cache, jnp.asarray(tokens), t)
+        return np.asarray(logits[:, -1].astype(jnp.float32))
+
+    def lowered_step(self):
+        """The decode step ``generate_batch`` runs, lowered for this
+        engine's params and batch (``jax.stages.Lowered``): ``.compile()``
+        it to time the compile or to read the program text."""
+        self._check_mesh("lowered_step")
+        cache = self.model.init_cache(self.cfg, self.B, self.max_len)
+        tok = jnp.zeros((self.B, 1), jnp.int32)
+        return self._step_fn.lower(self.params, cache, tok, self.key,
+                                   jnp.int32(self.t))

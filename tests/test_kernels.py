@@ -85,3 +85,17 @@ def test_adc_ref_binary():
     s = jnp.asarray([[2.0, 2.0]])
     out = ref.adc_quantize_ref(p, s, 1)
     np.testing.assert_allclose(np.asarray(out), [[-2.0, 2.0]])
+
+
+@pytest.mark.parametrize("backend,interpret", [("cpu", True), ("tpu", False),
+                                               ("gpu", None)])
+def test_interpret_mode_follows_the_backend(monkeypatch, backend, interpret):
+    """Interpret mode is for the CPU backend (the tests) only: TPU compiles
+    the kernels, and any other backend is refused rather than quietly
+    interpreted."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="gpu"):
+            ops.interpret_mode()
+    else:
+        assert ops.interpret_mode() is interpret

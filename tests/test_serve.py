@@ -92,3 +92,61 @@ def test_engine_from_artifact_serves_deploy_backend(setup, tmp_path):
     eng_e = ServingEngine(qmodel, qcfg, qparams, batch_size=2, max_len=32)
     out_emulate = eng_e.generate_batch(prompts, 3)
     assert np.array_equal(out_deploy, out_emulate)
+
+
+def test_serve_launcher_deploy_runs_on_the_kernels():
+    """``launch/serve.py --cim deploy`` serves a packed artifact whose
+    config dispatches the Pallas kernels, not the jnp oracle."""
+    from repro.launch import serve
+    args = serve.parse_args(["--arch", "olmo-1b", "--reduced", "--cim",
+                             "deploy", "--batch", "2", "--max-len", "16"])
+    engine, cfg = serve.build_engine(args)
+    assert engine.cfg.cim.mode == "deploy"
+    assert engine.cfg.cim.use_kernel
+    out = engine.generate_batch(np.zeros((2, 3), np.int32), 2)
+    assert out.shape == (2, 2) and out.max() < cfg.vocab
+
+
+def test_engine_inspection_matches_generation(setup):
+    """``prefill_logits`` is the program ``generate_batch`` prefills with
+    (its argmax is the first generated token), ``decode_logits`` fed that
+    token gives the second, and ``lowered_step`` is the decode step it
+    runs."""
+    cfg, model, params = setup
+    eng = ServingEngine(model, cfg, params, batch_size=2, max_len=32)
+    prompts = np.asarray(jax.random.randint(jax.random.PRNGKey(5), (2, 4),
+                                            0, cfg.vocab), np.int32)
+    logits = eng.prefill_logits(prompts)
+    assert logits.shape == (2, 4, cfg.vocab)
+    out = eng.generate_batch(prompts, 2)
+    np.testing.assert_array_equal(logits[:, -1].argmax(-1), out[:, 0])
+    step = eng.decode_logits(prompts, out[:, :1])
+    assert step.shape == (2, cfg.vocab)
+    np.testing.assert_array_equal(step.argmax(-1), out[:, 1])
+    assert "dot_general" in eng.lowered_step().as_text()
+
+
+@pytest.mark.parametrize("env_dir", [None, "from-env"])
+def test_compile_cache_dir(monkeypatch, tmp_path, env_dir):
+    """``JAX_COMPILATION_CACHE_DIR`` wins when set; otherwise the cache
+    lives at the fixed, git-ignored ``<checkout>/.jax_cache``."""
+    import os
+
+    from repro.launch import compile_cache
+    want = compile_cache.DEFAULT_CACHE_DIR
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        want = str(tmp_path / env_dir)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        assert compile_cache.enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert compile_cache.DEFAULT_CACHE_DIR == os.path.join(checkout,
+                                                           ".jax_cache")
+    with open(os.path.join(checkout, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
