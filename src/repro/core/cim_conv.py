@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.obs import adc as obs_adc
+from repro.obs import names
 
 from .bitsplit import place_values, split_digits
 from .cim_linear import CIMConfig, _deprecated, _quantize_act, deploy_act_codes
@@ -264,14 +265,15 @@ def _forward_conv_deploy(x, params, cfg: CIMConfig, stride, padding,
         f"geometry than x/cfg imply: expected (k_tiles, c_per_array)="
         f"{(t.k_tiles, cpa)}, packed {(k_tiles, c_per_array)}")
 
-    s_p = t.broadcast_psum_scale(params["s_p"])              # (S, kt, co)
-    s_w = t.broadcast_weight_scale(params["s_w"])            # (kt, co)
-    places = place_values(cfg.weight_bits, cfg.cell_bits)    # (S,)
-    deq = places[:, None, None] * s_w[None] * jnp.maximum(s_a, 1e-9)
-    if "deq_scale" in params:
-        # in-service recalibration correction (eval/recalibrate.py): a
-        # per-column dequant gain shipped as a ScaleDelta, (S, kt, co)
-        deq = deq * params["deq_scale"]
+    with jax.named_scope(names.SCOPE_ACT_QUANT):
+        s_p = t.broadcast_psum_scale(params["s_p"])          # (S, kt, co)
+        s_w = t.broadcast_weight_scale(params["s_w"])        # (kt, co)
+        places = place_values(cfg.weight_bits, cfg.cell_bits)  # (S,)
+        deq = places[:, None, None] * s_w[None] * jnp.maximum(s_a, 1e-9)
+        if "deq_scale" in params:
+            # in-service recalibration correction (eval/recalibrate.py):
+            # a per-column dequant gain shipped as a ScaleDelta, (S, kt, co)
+            deq = deq * params["deq_scale"]
 
     y = kops.cim_conv(
         a_int, digits, s_p, deq,

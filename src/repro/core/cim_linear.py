@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.obs import adc as obs_adc
+from repro.obs import names
 
 from .bitsplit import place_values, split_digits
 from .granularity import ArrayTiling, Granularity
@@ -215,13 +216,15 @@ def _quantize_act(x, params, cfg: CIMConfig):
     return a_hat / jnp.maximum(s_a, 1e-9), s_a
 
 
+@jax.named_scope(names.SCOPE_ACT_QUANT)
 def deploy_act_codes(x, s_a, cfg: CIMConfig) -> jnp.ndarray:
     """Integer activation codes for the packed inference paths.
 
     Shared by every packed backend (deploy/ref/adc_free/binary): clip-round
     x to the act_bits grid and narrow to the smallest integer dtype so HBM
     traffic drops to 1 byte/activation (the byte width
-    bench_kernel.traffic_model charges)."""
+    bench_kernel.traffic_model charges). Runs under the ``cim.act_quant``
+    scope."""
     qn_a, qp_a = qrange(cfg.act_bits, cfg.act_signed)
     a_int = jnp.clip(
         jnp.round(x.astype(jnp.float32) / jnp.maximum(s_a, 1e-9)),
@@ -360,16 +363,18 @@ def _forward_deploy(x, params, cfg, variation_key, sigma, compute_dtype,
                    else t.array_rows)    # uint8 planes: half-split pack
     assert t.k_tiles == digits.shape[1] and rows_stored == digits.shape[2], \
         (t.k_tiles, t.array_rows, digits.shape)
-    a_t = _tile_inputs(a_int, t)
+    with jax.named_scope(names.SCOPE_LAYOUT):
+        a_t = _tile_inputs(a_int, t)
 
-    s_p = _full_psum_scale(params, t)
-    s_w = _full_weight_scale(params, t)
-    places = place_values(cfg.weight_bits, cfg.cell_bits)
-    deq = places[:, None, None] * s_w[None] * jnp.maximum(s_a, 1e-9)
-    if "deq_scale" in params:
-        # in-service recalibration correction (eval/recalibrate.py): a
-        # per-column dequant gain shipped as a ScaleDelta, (S, kt, N)
-        deq = deq * params["deq_scale"]
+    with jax.named_scope(names.SCOPE_ACT_QUANT):
+        s_p = _full_psum_scale(params, t)
+        s_w = _full_weight_scale(params, t)
+        places = place_values(cfg.weight_bits, cfg.cell_bits)
+        deq = places[:, None, None] * s_w[None] * jnp.maximum(s_a, 1e-9)
+        if "deq_scale" in params:
+            # in-service recalibration correction (eval/recalibrate.py): a
+            # per-column dequant gain shipped as a ScaleDelta, (S, kt, N)
+            deq = deq * params["deq_scale"]
 
     y = kops.cim_matmul(
         a_t, digits, s_p, deq,

@@ -36,6 +36,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.obs import names
+
 from .cim_matmul import _block_psum, _zero_at_start, fused_grid_call
 from .ref import extract_conv_patches
 
@@ -88,6 +90,7 @@ def cim_matmul_adc_free_pallas(
     # amplifies through the next layer's activation-code rounding
     return fused_grid_call(_kernel, a_t, digits, ((deq, 0.0),),
                            variation_key, variation_std, occ,
+                           name=names.KERNEL_CIM_ADC_FREE,
                            nibble_groups=nibble_groups, block_m=block_m,
                            block_n=block_n, interpret=interpret)
 

@@ -71,6 +71,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.nibble import unpack_nibbles
 from repro.core.variation import perturb_digits, variation_wanted
+from repro.obs import names
 
 
 def _adc_quantize(p, sp, *, psum_bits: int):
@@ -190,10 +191,13 @@ def block_occupancy(occ: jnp.ndarray, bn: int) -> jnp.ndarray:
 
 
 def fused_grid_call(kernel, a_t, digits, cols, variation_key,
-                    variation_std, occ, *, nibble_groups: int, block_m: int,
-                    block_n: int, interpret: bool) -> jnp.ndarray:
+                    variation_std, occ, *, name: str, nibble_groups: int,
+                    block_m: int, block_n: int,
+                    interpret: bool) -> jnp.ndarray:
     """The wrapper the ADC and ADC-free deploy kernels share: variation,
     padding, the tile-major layout, the grid and the occupancy table.
+    The relayout runs under the ``cim.layout`` scope and the kernel,
+    named ``name`` in the trace, under ``cim.kernel``.
 
     ``kernel(*refs, nibble, groups, table)`` is the body; its refs are
     the skip table (with ``occ``), the activations, the digits, one ref
@@ -220,11 +224,15 @@ def fused_grid_call(kernel, a_t, digits, cols, variation_key,
     bm = min(block_m, m)
     bn = min(block_n, n)
     pad_n = (-n) % bn
-    a_t = tile_major(a_t, bm)            # (k_tiles, mp, rows)
+    with jax.named_scope(names.SCOPE_LAYOUT):
+        a_t = tile_major(a_t, bm)        # (k_tiles, mp, rows)
+        if pad_n:
+            digits = jnp.pad(digits, ((0, 0), (0, 0), (0, 0), (0, pad_n)))
+            if occ is not None:
+                occ = jnp.pad(occ, ((0, 0), (0, 0), (0, pad_n)))  # dead
+        cols = tuple(scale_slab(c, pad_n, value) for c, value in cols)
+        table = None if occ is None else block_occupancy(occ, bn)
     mp, np_ = a_t.shape[1], n + pad_n
-    if pad_n:
-        digits = jnp.pad(digits, ((0, 0), (0, 0), (0, 0), (0, pad_n)))
-    cols = tuple(scale_slab(c, pad_n, value) for c, value in cols)
 
     grid = (mp // bm, np_ // bn, n_split, k_tiles)
     col_spec = pl.BlockSpec((1, 1, bn),
@@ -236,24 +244,25 @@ def fused_grid_call(kernel, a_t, digits, cols, variation_key,
     out_spec = pl.BlockSpec((bm, bn), lambda i, j, s, t, *_: (i, j))
     body = functools.partial(kernel, nibble=nibble, groups=nibble_groups)
     args = (a_t, digits) + cols
-    if occ is None:
+    if table is None:
         grid_spec = pl.GridSpec(grid=grid, in_specs=in_specs,
                                 out_specs=out_spec)
     else:
-        if pad_n:
-            occ = jnp.pad(occ, ((0, 0), (0, 0), (0, pad_n)))  # dead: skip
         body = functools.partial(body, table=(k_tiles, np_ // bn))
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
             out_specs=out_spec)
-        args = (block_occupancy(occ, bn),) + args
-    out = pl.pallas_call(
-        body,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
-        interpret=interpret,
-    )(*args)
-    return out[:m, :n]
+        args = (table,) + args
+    with jax.named_scope(names.SCOPE_KERNEL):
+        out = pl.pallas_call(
+            body,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
+            interpret=interpret,
+            name=name,
+        )(*args)
+    with jax.named_scope(names.SCOPE_LAYOUT):
+        return out[:m, :n]
 
 
 @functools.partial(
@@ -283,8 +292,9 @@ def cim_matmul_pallas(
         functools.partial(_kernel, psum_bits=psum_bits,
                           psum_quant=psum_quant),
         a_t, digits, ((s_p, 1.0), (deq, 0.0)), variation_key,
-        variation_std, occ, nibble_groups=nibble_groups, block_m=block_m,
-        block_n=block_n, interpret=interpret)
+        variation_std, occ, name=names.KERNEL_CIM_MATMUL,
+        nibble_groups=nibble_groups, block_m=block_m, block_n=block_n,
+        interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -340,31 +350,35 @@ def cim_matmul_experts_pallas(
     bm = min(block_m, m)
     bn = min(block_n, n)
     pad_n = (-n) % bn
-    a_t = tile_major(a_t, bm)            # (E, k_tiles, mp, rows)
+    with jax.named_scope(names.SCOPE_LAYOUT):
+        a_t = tile_major(a_t, bm)        # (E, k_tiles, mp, rows)
+        if pad_n:
+            digits = jnp.pad(digits,
+                             ((0, 0), (0, 0), (0, 0), (0, 0), (0, pad_n)))
+        s_p = scale_slab(s_p, pad_n, 1.0)
+        deq = scale_slab(deq, pad_n)
     mp, np_ = a_t.shape[2], n + pad_n
-    if pad_n:
-        digits = jnp.pad(digits,
-                         ((0, 0), (0, 0), (0, 0), (0, 0), (0, pad_n)))
-    s_p = scale_slab(s_p, pad_n, 1.0)
-    deq = scale_slab(deq, pad_n)
 
     grid = (e, mp // bm, np_ // bn, n_split, k_tiles)
     col_spec = pl.BlockSpec(
         (1, 1, 1, bn), lambda ei, i, j, s, t: (ei, s * k_tiles + t, 0, j))
-    out = pl.pallas_call(
-        functools.partial(_experts_kernel, psum_bits=psum_bits,
-                          psum_quant=psum_quant),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, bm, rows),
-                         lambda ei, i, j, s, t: (ei, t, i, 0)),
-            pl.BlockSpec((1, 1, 1, rows, bn),
-                         lambda ei, i, j, s, t: (ei, s, t, 0, j)),
-            col_spec, col_spec,
-        ],
-        out_specs=pl.BlockSpec((1, bm, bn),
-                               lambda ei, i, j, s, t: (ei, i, j)),
-        out_shape=jax.ShapeDtypeStruct((e, mp, np_), jnp.float32),
-        interpret=interpret,
-    )(a_t, digits, s_p, deq)
-    return out[:, :m, :n]
+    with jax.named_scope(names.SCOPE_KERNEL):
+        out = pl.pallas_call(
+            functools.partial(_experts_kernel, psum_bits=psum_bits,
+                              psum_quant=psum_quant),
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, 1, bm, rows),
+                             lambda ei, i, j, s, t: (ei, t, i, 0)),
+                pl.BlockSpec((1, 1, 1, rows, bn),
+                             lambda ei, i, j, s, t: (ei, s, t, 0, j)),
+                col_spec, col_spec,
+            ],
+            out_specs=pl.BlockSpec((1, bm, bn),
+                                   lambda ei, i, j, s, t: (ei, i, j)),
+            out_shape=jax.ShapeDtypeStruct((e, mp, np_), jnp.float32),
+            interpret=interpret,
+            name=names.KERNEL_CIM_EXPERTS,
+        )(a_t, digits, s_p, deq)
+    with jax.named_scope(names.SCOPE_LAYOUT):
+        return out[:, :m, :n]

@@ -13,6 +13,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.obs import names
+
 HIGHEST = jax.lax.Precision.HIGHEST
 
 
@@ -113,6 +115,7 @@ def conv_pads(h: int, w: int, kh: int, kw: int, stride: int, padding):
     return tuple((int(lo), int(hi)) for lo, hi in padding)
 
 
+@jax.named_scope(names.SCOPE_PATCHES)
 def extract_conv_patches(
     a: jnp.ndarray,        # (B, H, W, C)
     kh: int, kw: int,
@@ -130,6 +133,7 @@ def extract_conv_patches(
     ``c_per_array = floor(rows / K^2)`` rule so channel slices never
     straddle an array boundary. Channels are zero-padded to
     ``k_tiles * c_per_array`` (matching the emulate path's padding).
+    Runs under the ``cim.patches`` scope, whoever calls it.
     """
     b, h, w, c = a.shape
     pads = conv_pads(h, w, kh, kw, stride, padding)

@@ -24,7 +24,7 @@ loop — past the hard threshold the engine re-fits the per-column scales
 in place instead of degrading to the digital fallback.
 
 Telemetry (DESIGN.md §12): ``--metrics-out PATH`` dumps the engine's
-folded ``metrics()`` view (health + throughput + registry snapshot, and
+folded ``metrics()`` view (health + token counts + registry snapshot, and
 ADC saturation when ``--adc-sample`` arms the collector) as JSON after
 generation; ``--report-every N`` prints a one-line operator report to
 stderr every N decode steps.
@@ -74,8 +74,8 @@ def parse_args(argv=None) -> argparse.Namespace:
                     help="recalibrate column scales automatically on "
                          "hard drift instead of serving the fallback")
     ap.add_argument("--metrics-out", default=None, metavar="PATH",
-                    help="write engine.metrics() (health + throughput + "
-                         "metric snapshot) as JSON after generation")
+                    help="write engine.metrics() (health + token counts "
+                         "+ metric snapshot) as JSON after generation")
     ap.add_argument("--report-every", type=int, default=0, metavar="N",
                     help="print a one-line metrics report to stderr every "
                          "N decode steps (0 = off)")

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 from repro.core.cim_conv import _conv_forward, _init_conv
 from repro.core.cim_linear import CIMConfig, _deprecated
+from repro.obs import names as obs_names
 
 
 @dataclasses.dataclass(frozen=True)
@@ -148,55 +149,59 @@ def forward(params: Dict, state: Dict, x: jnp.ndarray, cfg: ResNetConfig,
     a traced scalar so sigma sweeps don't recompile). With
     ``return_taps=True`` also returns {layer_name: conv input activation}
     — the hook the robustness harness uses for per-layer attribution.
+
+    Each layer runs under a ``jax.named_scope`` (``obs.names``):
+    ``resnet.stem``, ``resnet.s{i}b{j}`` with a nested scope per conv
+    named by its parameter key, and ``resnet.head``.
     """
     widths = cfg.widths if cfg.depth == 20 else (64, 128, 256, 512)
     nb = cfg.blocks_per_stage
     new_state: Dict = {}
     taps: Dict[str, jnp.ndarray] = {}
     fp = cfg.cim.replace(enabled=False)
-    h = _conv_forward(x, params["stem"], fp, compute_dtype=jnp.float32)
-    h, new_state["stem_bn"] = _bn_apply(params["stem_bn"], state["stem_bn"],
-                                        h, train, cfg.bn_momentum)
-    h = jax.nn.relu(h)
+    with jax.named_scope(obs_names.SCOPE_RESNET_STEM):
+        h = _conv_forward(x, params["stem"], fp, compute_dtype=jnp.float32)
+        h, new_state["stem_bn"] = _bn_apply(
+            params["stem_bn"], state["stem_bn"], h, train, cfg.bn_momentum)
+        h = jax.nn.relu(h)
     vkeys = variation_keys(variation_key, cfg) or {}
+
+    def conv(name, key, x_in, stride=1):
+        if return_taps:
+            taps[f"{name}.{key}"] = x_in
+        with jax.named_scope(key):
+            return _conv_forward(x_in, params[name][key], cfg.cim,
+                                 stride=stride,
+                                 variation_key=vkeys.get(f"{name}.{key}"),
+                                 variation_std=variation_std,
+                                 compute_dtype=jnp.float32)
+
     for si, w in enumerate(widths):
         for bi in range(nb):
             name = f"s{si}b{bi}"
             blk, bst = params[name], state[name]
             nst: Dict = {}
             stride = 2 if (bi == 0 and si > 0) else 1
-            if return_taps:
-                taps[f"{name}.conv1"] = h
-            y = _conv_forward(h, blk["conv1"], cfg.cim, stride=stride,
-                              variation_key=vkeys.get(f"{name}.conv1"),
-                              variation_std=variation_std,
-                              compute_dtype=jnp.float32)
-            y, nst["bn1"] = _bn_apply(blk["bn1"], bst["bn1"], y, train,
-                                      cfg.bn_momentum)
-            y = jax.nn.relu(y)
-            if return_taps:
-                taps[f"{name}.conv2"] = y
-            y = _conv_forward(y, blk["conv2"], cfg.cim,
-                              variation_key=vkeys.get(f"{name}.conv2"),
-                              variation_std=variation_std,
-                              compute_dtype=jnp.float32)
-            y, nst["bn2"] = _bn_apply(blk["bn2"], bst["bn2"], y, train,
-                                      cfg.bn_momentum)
-            if "proj" in blk:
-                if return_taps:
-                    taps[f"{name}.proj"] = h
-                sc = _conv_forward(h, blk["proj"], cfg.cim, stride=stride,
-                                   variation_key=vkeys.get(f"{name}.proj"),
-                                   variation_std=variation_std,
-                                   compute_dtype=jnp.float32)
-                sc, nst["bn_p"] = _bn_apply(blk["bn_p"], bst["bn_p"], sc,
-                                            train, cfg.bn_momentum)
-            else:
-                sc = h
-            h = jax.nn.relu(y + sc)
+            with jax.named_scope(obs_names.SCOPE_RESNET_BLOCK.format(
+                    stage=si, block=bi)):
+                y = conv(name, "conv1", h, stride)
+                y, nst["bn1"] = _bn_apply(blk["bn1"], bst["bn1"], y, train,
+                                          cfg.bn_momentum)
+                y = jax.nn.relu(y)
+                y = conv(name, "conv2", y)
+                y, nst["bn2"] = _bn_apply(blk["bn2"], bst["bn2"], y, train,
+                                          cfg.bn_momentum)
+                if "proj" in blk:
+                    sc = conv(name, "proj", h, stride)
+                    sc, nst["bn_p"] = _bn_apply(blk["bn_p"], bst["bn_p"], sc,
+                                                train, cfg.bn_momentum)
+                else:
+                    sc = h
+                h = jax.nn.relu(y + sc)
             new_state[name] = nst
-    h = jnp.mean(h, axis=(1, 2))
-    logits = h @ params["fc"]["w"] + params["fc"]["b"]
+    with jax.named_scope(obs_names.SCOPE_RESNET_HEAD):
+        h = jnp.mean(h, axis=(1, 2))
+        logits = h @ params["fc"]["w"] + params["fc"]["b"]
     if return_taps:
         return logits, new_state, taps
     return logits, new_state

@@ -11,7 +11,7 @@ percentiles are computed exactly at snapshot time with numpy-compatible
 linear interpolation, rather than approximated from fixed buckets; the
 load bench's p50/p99 come straight from these.
 
-``log_event`` appends structured events (request lifecycle, spans,
+``log_event`` appends structured events (request lifecycle,
 recalibrations) to an in-memory ring and, when the registry was built
 with ``event_log_path``, to a JSONL file — one JSON object per line,
 each stamped with ``ts`` (epoch seconds) and ``kind``.

@@ -1,10 +1,11 @@
-"""Canonical metric names (DESIGN.md §12).
+"""Canonical metric, scope and kernel names (DESIGN.md §12).
 
-Every metric the serving engine, kernels, and benches emit is named
-here, not at the emission site — the DESIGN.md §12 table is checked
-against this module by ``tools/check_metrics.py`` (CI docs job), so a
-renamed or deleted metric fails the build instead of silently breaking
-a dashboard.
+Every metric the serving engine, kernels, and benches emit, every
+``jax.named_scope`` the deploy path opens and every Pallas kernel name
+is named here, not at the emission site — the DESIGN.md §12 tables are
+checked against this module by ``tools/check_metrics.py`` (CI docs job),
+so a renamed or deleted name fails the build instead of silently
+breaking a dashboard or a benchmark reader.
 
 Naming scheme: dot-separated ``<plane>.<subsystem>.<what>``; histograms
 of durations end in ``.seconds``. Prometheus exposition sanitizes dots
@@ -49,3 +50,54 @@ ADC_SATURATED = "cim.adc.saturated"
 ADC_COL_SATURATION_RATE = "cim.adc.col_saturation_rate"
 #: histogram: per-column mean ADC range occupancy |q|/q_max
 ADC_OCCUPANCY = "cim.adc.occupancy"
+
+# -- compile plane (recorded by repro.obs.compiles, from jax.monitoring) ----
+
+#: counter: XLA backend compile requests — a fresh compile or a load from
+#: the persistent compilation cache (``jit.cache.hits`` counts the loads)
+JIT_COMPILES = "jit.compiles"
+#: histogram: seconds per backend compile request
+JIT_COMPILE_SECONDS = "jit.compile.seconds"
+#: counter: compile requests served from the persistent compilation cache
+JIT_CACHE_HITS = "jit.cache.hits"
+#: counter: compiles written to the persistent compilation cache (JAX
+#: records its miss event on the write; a compile under the cache's
+#: minimum compile time is neither a hit nor a miss)
+JIT_CACHE_MISSES = "jit.cache.misses"
+
+# -- named scopes (``jax.named_scope``) --------------------------------------
+#
+# Trace-time metadata: each lands in the compiled HLO's
+# ``metadata={op_name=...}`` path of the ops traced inside it (a fusion
+# takes its root's), so device time in a profile can be put down to the
+# layer that issued it. Nothing runs for them.
+
+#: scope: activation codes and the dequant scale arithmetic of a CIM layer
+SCOPE_ACT_QUANT = "cim.act_quant"
+#: scope: stretched-kernel patch extraction (``ref.extract_conv_patches``)
+SCOPE_PATCHES = "cim.patches"
+#: scope: the deploy kernels' operand relayout — tile-major activations,
+#: scale slabs, the occupancy table, padding before the kernel and the
+#: slice after it
+SCOPE_LAYOUT = "cim.layout"
+#: scope: the deploy kernel's ``pallas_call``
+SCOPE_KERNEL = "cim.kernel"
+#: scope: the ResNet stem (full-precision conv, BN, ReLU)
+SCOPE_RESNET_STEM = "resnet.stem"
+#: scope template: one ResNet basic block; each of its convs nests a
+#: scope named by the conv's parameter key (``conv1``, ``conv2``, ``proj``)
+SCOPE_RESNET_BLOCK = "resnet.s{stage}b{block}"
+#: scope: the ResNet head (average pool and classifier)
+SCOPE_RESNET_HEAD = "resnet.head"
+
+# -- Pallas kernel names (``pallas_call(name=...)``) -------------------------
+#
+# The device trace names a kernel's op after this name plus a counter
+# (``cim_matmul.3``); every CIM kernel's name starts with ``cim_``.
+
+#: kernel: the fused CIM matmul with the column ADC
+KERNEL_CIM_MATMUL = "cim_matmul"
+#: kernel: the ADC-free CIM matmul (digital psum accumulation)
+KERNEL_CIM_ADC_FREE = "cim_adc_free"
+#: kernel: the batched MoE expert-bank CIM matmul
+KERNEL_CIM_EXPERTS = "cim_experts"

@@ -1,18 +1,20 @@
 """Lightweight tracing spans (DESIGN.md §12).
 
 A span measures one host-side region — a request's prefill, one decode
-step, a recalibration fit — and records on exit:
-
-* a duration observation into ``<name>.seconds`` on the tracer's
-  registry (so spans and metrics share one export path), and
-* a ``span`` event in the registry's event log carrying the span's
-  name, duration, attributes and its parent span's name.
+step, a recalibration fit — and on exit records a duration observation
+into ``<name>.seconds`` on the tracer's registry (so spans and metrics
+share one export path) and a ``SpanRecord`` in ``Tracer.spans``.
 
 Nesting is tracked per-thread with a plain stack: a span opened inside
 another records that span as its parent, which is all the structure the
-serving engine needs (request -> prefill -> per-layer would be the next
-refinement). Spans never trace into jit — they time the host-side
-dispatch like any external observer would.
+serving engine needs.
+
+Each span also opens a ``jax.profiler.TraceAnnotation`` of its name, so
+under a running profiler it lands in the trace's host plane, on the
+device trace's clock: a device idle gap can then be put down to the span
+the host was in. With no profiler running the annotation costs a flag
+check. Device time inside jitted code is named by ``jax.named_scope``
+(``obs.names.SCOPE_*``), not by spans.
 """
 from __future__ import annotations
 
@@ -21,6 +23,8 @@ import threading
 import time
 from contextlib import contextmanager
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from .metrics import MetricsRegistry
 
@@ -65,7 +69,8 @@ class Tracer:
         t0 = time.perf_counter()
         ts = time.time()
         try:
-            yield
+            with TraceAnnotation(name):
+                yield
         finally:
             dur = time.perf_counter() - t0
             stack.pop()
@@ -75,5 +80,3 @@ class Tracer:
             if len(self.spans) > self._max_spans:
                 del self.spans[: len(self.spans) - self._max_spans]
             self.registry.histogram(f"{name}.seconds").observe(dur)
-            self.registry.log_event("span", name=name, duration=dur,
-                                    parent=parent, **attrs)

@@ -19,9 +19,11 @@ and re-fits per-column scales in place via ``recalibrate()``.
 Telemetry (DESIGN.md §12): every engine owns a ``repro.obs``
 ``MetricsRegistry`` (pass ``metrics=`` to share one). Request lifecycle
 is traced — queue wait, prefill and per-decode-step spans land in the
-registry's histograms and event log; token/request counters and queue
-depth/active-slot gauges update as the slots churn. ``metrics()`` folds
-all of it with ``health()``, derived throughput and — when the
+registry's histograms (and, under a running profiler, in its trace);
+token/request counters and queue depth/active-slot gauges update as the
+slots churn, and a ``CompileWatch`` counts compiles (``jit.*``) beside
+them, so a recompile shows next to the step it slowed. ``metrics()``
+folds all of it with ``health()``, token and step counts and — when the
 ``repro.obs.adc`` collector is armed — the ADC saturation summary into
 one JSON-safe view; ``launch/serve.py --metrics-out`` writes exactly
 that. When the collector is armed the monitor additionally ingests an
@@ -34,6 +36,7 @@ import dataclasses
 import os
 import sys
 import time
+import weakref
 from typing import Callable, Dict, List, Optional
 
 import jax
@@ -45,6 +48,7 @@ from repro.core.variation import DriftSchedule, DriftState, drift_tree
 from repro.models.registry import ModelFns
 from repro.obs import adc as obs_adc
 from repro.obs import names as M
+from repro.obs.compiles import CompileWatch
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.tracing import Tracer
 
@@ -239,6 +243,8 @@ class ServingEngine:
         self.retired = 0                    # requests completed, ever
         self.registry = metrics if metrics is not None else MetricsRegistry()
         self.tracer = Tracer(self.registry)
+        # process-wide listeners: unregistered when the engine goes
+        weakref.finalize(self, CompileWatch(self.registry).close)
         self.report_every = report_every    # stderr line every N decode steps
         self._decode_steps = 0
         self._last_sat = 0                  # adc totals at last observation,
@@ -378,24 +384,21 @@ class ServingEngine:
 
     def metrics(self) -> Dict:
         """One folded telemetry view (DESIGN.md §12): ``health()`` plus
-        derived throughput, the ADC saturation summary (when the
-        collector is armed) and the full registry snapshot. JSON-safe —
-        ``launch/serve.py --metrics-out`` dumps it verbatim."""
+        token and decode-step counts with the device count, the ADC
+        saturation summary (when the collector is armed) and the full
+        registry snapshot. JSON-safe — ``launch/serve.py --metrics-out``
+        dumps it verbatim."""
         if obs_adc.enabled():
             obs_adc.sync()
-        toks = self.registry.counter(M.TOKENS_GENERATED).value
-        dec = self.registry.histogram(M.DECODE_STEP_SECONDS)
-        tps = toks / dec.sum if dec.sum > 0 else 0.0
         n_dev = 1 if self.mesh is None else int(self.mesh.devices.size)
         return {
             "health": self.health(),
             "throughput": {
-                "tokens_generated": toks,
-                "decode_steps": dec.count,
-                "decode_seconds": dec.sum,
-                "tokens_per_sec": tps,
+                "tokens_generated":
+                    self.registry.counter(M.TOKENS_GENERATED).value,
+                "decode_steps":
+                    self.registry.histogram(M.DECODE_STEP_SECONDS).count,
                 "devices": n_dev,
-                "tokens_per_sec_per_device": tps / n_dev,
             },
             "saturation": obs_adc.summary() if obs_adc.enabled() else None,
             "metrics": self.registry.snapshot(),

@@ -115,9 +115,54 @@ def test_span_nesting_and_histogram():
     assert outer.duration >= inner.duration >= 0.0
     assert reg.histogram("outer.seconds").count == 1
     assert reg.histogram("inner.seconds").count == 1
-    evs = reg.events("span")
-    assert {e["name"] for e in evs} == {"outer", "inner"}
-    assert next(e for e in evs if e["name"] == "inner")["parent"] == "outer"
+    assert outer.attrs == {"rid": 1} and inner.attrs == {}
+
+
+def test_span_opens_trace_annotation(monkeypatch):
+    """Each span opens a profiler TraceAnnotation of its own name, so it
+    lands in a device trace's host plane, nested as the spans are."""
+    import repro.obs.tracing as tracing
+    seen = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            seen.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            seen.append(("exit", self.name))
+
+    monkeypatch.setattr(tracing, "TraceAnnotation", Annotation)
+    tr = Tracer(MetricsRegistry())
+    with tr.span("serve.decode.step"):
+        with tr.span("inner"):
+            pass
+    assert seen == [("enter", "serve.decode.step"), ("enter", "inner"),
+                    ("exit", "inner"), ("exit", "serve.decode.step")]
+
+
+def test_compile_watch_counts_one_compile_per_new_program():
+    """A fresh jit compile adds exactly one to ``jit.compiles`` and one
+    observation to ``jit.compile.seconds``; calling it again adds none;
+    after ``close()`` nothing is counted."""
+    from repro.obs import CompileWatch
+    reg = MetricsRegistry()
+    x = jnp.arange(7.0)
+    f = jax.jit(lambda v: v * 3.0 - 1.0)
+    watch = CompileWatch(reg)
+    try:
+        f(x).block_until_ready()
+        assert reg.counter(M.JIT_COMPILES).value == 1
+        assert reg.histogram(M.JIT_COMPILE_SECONDS).count == 1
+        f(x).block_until_ready()
+        assert reg.counter(M.JIT_COMPILES).value == 1
+    finally:
+        watch.close()
+    watch.close()                                 # a second close is a no-op
+    jax.jit(lambda v: v + 2.0)(x).block_until_ready()
+    assert reg.counter(M.JIT_COMPILES).value == 1
 
 
 # ---------------------------------------------------------------------------
@@ -317,7 +362,10 @@ def test_engine_metrics_scripted_requests(lm_setup):
     assert snap["histograms"][M.QUEUE_WAIT_SECONDS]["count"] == 3
     assert snap["histograms"][M.PREFILL_SECONDS]["count"] == 3
     assert snap["histograms"][M.DECODE_STEP_SECONDS]["count"] >= 4
-    assert m["throughput"]["tokens_per_sec"] > 0
+    assert m["throughput"]["tokens_generated"] == snap["counters"][
+        M.TOKENS_GENERATED]
+    assert m["throughput"]["decode_steps"] >= 4
+    assert snap["counters"][M.JIT_COMPILES] >= 1  # the engine's own steps
     assert m["saturation"] is None               # collector not armed
 
     evs = eng.registry.events("request_completed")

@@ -13,6 +13,7 @@ so every test worker collects the same tests and only the worker that
 runs this file loads the TPU compiler.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -111,6 +112,29 @@ def test_conv_resnet18_3x3(one_chip, dtype):
     _assert_kernel(cim_conv_pallas.lower(
         a, d, sp, sp, None, None, occ, kh=3, kw=3, stride=1,
         padding="SAME", c_per_array=cpa, psum_bits=6, interpret=False))
+
+
+def test_conv_kernel_names_and_scopes(one_chip):
+    """The trace names a kernel op after its HLO instruction: every
+    Pallas call of the conv deploy path is named ``cim_...`` (the
+    ``pallas_call(name=...)``, not the jitted wrapper's name) and carries
+    the ``cim.kernel`` scope in its op_name, and the patch extraction
+    and relayout keep their scopes through the TPU compile."""
+    cpa, kt = 14, 5
+    a = _spec((8, 56, 56, 64), jnp.int8, one_chip)
+    d = _spec((S, kt, 9 * cpa // 2, 64), jnp.uint8, one_chip)
+    sp = _spec((S, kt, 64), jnp.float32, one_chip)
+    text = cim_conv_pallas.lower(
+        a, d, sp, sp, kh=3, kw=3, stride=1, padding="SAME",
+        c_per_array=cpa, psum_bits=6, interpret=False).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls
+    for line in calls:
+        name = line.split(" = ", 1)[0].split()[-1].lstrip("%")
+        assert re.fullmatch(r"cim_matmul(\.\d+)?", name), name
+        assert "/cim.kernel/" in re.search(r'op_name="([^"]*)"', line)[1]
+    assert "/cim.patches/" in text and "/cim.layout/" in text
 
 
 def test_matmul_adc_free_nibble_occupancy(one_chip):

@@ -6,8 +6,9 @@ a table; the single source of truth for those names is
 ``src/repro/obs/names.py``. This lint holds the two together, both
 ways, statically (ast — no jax import needed):
 
-* every ``serve.*`` / ``cim.*`` metric name appearing in DESIGN.md §12
-  must be the value of a constant in ``repro/obs/names.py``;
+* every ``serve.*`` / ``cim.*`` / ``jit.*`` / ``resnet.*`` name and
+  every ``cim_*`` kernel name appearing in DESIGN.md §12 must be the
+  value of a constant in ``repro/obs/names.py``;
 * every constant in ``names.py`` must appear in the §12 table.
 
   python tools/check_metrics.py
@@ -23,8 +24,11 @@ REPO = Path(__file__).resolve().parent.parent
 NAMES_PY = REPO / "src" / "repro" / "obs" / "names.py"
 DESIGN = REPO / "DESIGN.md"
 
-#: backticked dotted names in the §12 table rows, e.g. `serve.queue.depth`
-NAME_RE = re.compile(r"`((?:serve|cim)\.[a-z0-9_.]+)`")
+#: backticked names in §12: dotted metric and scope names such as
+#: `serve.queue.depth` or `resnet.s{stage}b{block}`, and kernel names
+#: such as `cim_matmul`
+NAME_RE = re.compile(
+    r"`((?:serve|cim|jit|resnet)\.[a-z0-9_.{}]+|cim_[a-z0-9_]+)`")
 
 
 def declared_names() -> set[str]:
