@@ -9,16 +9,18 @@ resolution and becomes the digital accumulator width the cost model
 charges (benchmarks/bench_hw_cost.layer_cost(style="adc_free")); the
 kernel itself accumulates exactly.
 
-Relative to ``kernels/cim_matmul._kernel`` the body drops the ADC stage
-(round -> scale -> clip -> rescale in VMEM) *and* the s_p operand — the
-per-column ADC scale stream never leaves HBM because it does not exist
-on this hardware. Everything else is deliberately identical: same grid
-(M/bm, N/bn, n_split, k_tiles) with the reduction dims iterating
-fastest, same packed digit-plane layout, same trailing-N column-shard
-contract (kernels/ops dispatches this kernel per column shard under
-shard_map unchanged, DESIGN.md §10), and cell variation is injected on
-the unpadded packed planes before the pallas_call exactly like the ADC
-kernel — ``perturb_packed`` semantics carry over untouched (§8).
+Relative to the ADC kernel (``kernels/cim_matmul``) the epilogue drops
+the ADC stage (round -> scale -> clip -> rescale in VMEM) *and* the s_p
+operand — the per-column ADC scale stream never leaves HBM because it
+does not exist on this hardware. Everything else is deliberately
+identical: the same body and grid (``cim_matmul.fused_grid_call``:
+(M/bm, N/bn, n_split, k_tiles/tk), the reduction dims iterating fastest
+and the tile chunk walked in the body), same packed digit-plane layout,
+same trailing-N column-shard contract (kernels/ops dispatches this
+kernel per column shard under shard_map unchanged, DESIGN.md §10), and
+cell variation is injected on the unpadded packed planes before the
+pallas_call exactly like the ADC kernel — ``perturb_packed`` semantics
+carry over untouched (§8).
 
 Bit-exactness contract: psums are integer-valued (int x int MACs), so
 ``jnp.round`` on the f32 accumulator is the identity up to float
@@ -34,30 +36,21 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 from repro.obs import names
 
-from .cim_matmul import _block_psum, _zero_at_start, fused_grid_call
+from .cim_matmul import BLOCK_M_MAX, BLOCK_N_MAX, fused_grid_call
 from .ref import extract_conv_patches
 
 
-def _kernel(*refs, nibble: bool = False, groups: int = 1, table=None):
-    """Grid (i, j, s, t). With ``table`` (the occupancy skip) the first
-    ref is the scalar-prefetched block table: a dead block skips the MAC
-    (``cim_matmul._block_psum``). No sign-ADC subtlety exists here: an
-    all-zero plane's exact digital psum is 0, so dense and skip both add
-    +0.0 — bit-identical on a +0.0-initialized f32 accumulator."""
-    occ_ref = refs[0] if table is not None else None
-    a_ref, d_ref, deq_ref, o_ref = refs[-4:]
-    j, s, t = pl.program_id(1), pl.program_id(2), pl.program_id(3)
-    _zero_at_start(o_ref, t, s)
-    p = _block_psum(occ_ref, a_ref, d_ref, nibble=nibble, groups=groups,
-                    table=table, j=j, t=t, s=s)
-    # digital accumulation: snap the integer-valued MACs (kills float
-    # roundoff, matching the ADC kernel's pre-quantize snap) and add the
-    # dequantized word straight into the accumulator — no ADC stage
-    o_ref[...] += jnp.round(p) * deq_ref[0, 0, :].astype(jnp.float32)[None, :]
+def _digital(p, deq):
+    """Digital accumulation of one array tile's psum block: snap the
+    integer-valued MACs (kills float roundoff, matching the ADC kernel's
+    pre-quantize snap) and dequantize — no ADC stage. A dead block of the
+    occupancy skip (``cim_matmul._grid_body``) has no sign-ADC subtlety
+    here: an all-zero plane's exact digital psum is 0, so dense and skip
+    both add +0.0 — bit-identical on a +0.0-initialized accumulator."""
+    return jnp.round(p) * deq.astype(jnp.float32)[None, :]
 
 
 @functools.partial(
@@ -73,8 +66,8 @@ def cim_matmul_adc_free_pallas(
     occ=None,              # optional (S, k_tiles, N) uint8 occupancy map
     *,
     nibble_groups: int = 1,
-    block_m: int = 128,
-    block_n: int = 128,
+    block_m: int = BLOCK_M_MAX,
+    block_n: int = BLOCK_N_MAX,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """ADC-free CIM matmul: digital accumulation of bit-sliced psums.
@@ -88,7 +81,7 @@ def cim_matmul_adc_free_pallas(
     # kernel and of the oracle (``ref.shift_add``) — unquantized psums
     # carry full mantissas, so any reassociation is visible at 1 ulp and
     # amplifies through the next layer's activation-code rounding
-    return fused_grid_call(_kernel, a_t, digits, ((deq, 0.0),),
+    return fused_grid_call(_digital, a_t, digits, ((deq, 0.0),),
                            variation_key, variation_std, occ,
                            name=names.KERNEL_CIM_ADC_FREE,
                            nibble_groups=nibble_groups, block_m=block_m,
@@ -113,8 +106,8 @@ def cim_conv_adc_free_pallas(
     stride: int,
     padding: str,
     c_per_array: int,
-    block_m: int = 128,
-    block_n: int = 128,
+    block_m: int = BLOCK_M_MAX,
+    block_n: int = BLOCK_N_MAX,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """ADC-free CIM conv: same stretched-kernel lowering as

@@ -25,13 +25,14 @@ per-cell noise from a shared key; DESIGN.md §8.)
      kernel re-reads the same patch block per bit-split via its BlockSpec
      index map (the a-operand map ignores the split index).
   2. The spatial axis flattens to M = B*H'*W' and lowers onto the fused
-     CIM matmul kernel, whose grid (M/bm, C_out/bn, n_split, k_tiles)
-     applies ADC quantization to each array-tile accumulator in VMEM —
-     the partial-sum tensor never touches HBM (DESIGN.md §7).
+     CIM matmul kernel, whose grid (M/bm, C_out/bn, n_split,
+     k_tiles/tk) applies ADC quantization to each array-tile
+     accumulator in VMEM — the partial-sum tensor never touches HBM
+     (DESIGN.md §7).
 
-VMEM working set per grid step is the linear kernel's (DESIGN.md §6);
-rows = kh*kw*c_per_array <= array_rows, so conv blocks are never larger
-than the linear blocks the budget was sized for.
+The block shape and its VMEM working set come from the same chooser
+as the linear kernel's (``cim_matmul.block_shape``, DESIGN.md §6), with
+rows = kh*kw*c_per_array <= array_rows.
 
 Shard-axis invariant (DESIGN.md §10): the trailing C_out axis of the
 flattened planes/scales is the column-parallel shard axis. Patches are
@@ -47,7 +48,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .cim_matmul import cim_matmul_pallas
+from .cim_matmul import BLOCK_M_MAX, BLOCK_N_MAX, cim_matmul_pallas
 from .ref import extract_conv_patches
 
 
@@ -73,8 +74,8 @@ def cim_conv_pallas(
     c_per_array: int,
     psum_bits: int,
     psum_quant: bool = True,
-    block_m: int = 128,
-    block_n: int = 128,
+    block_m: int = BLOCK_M_MAX,
+    block_n: int = BLOCK_N_MAX,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Fused CIM conv: stretched-kernel patches -> tiled matmul kernel.

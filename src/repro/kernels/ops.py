@@ -47,7 +47,8 @@ from repro.obs import adc as obs_adc
 from . import ref
 from .cim_adc_free import cim_conv_adc_free_pallas, cim_matmul_adc_free_pallas
 from .cim_conv import cim_conv_pallas
-from .cim_matmul import cim_matmul_experts_pallas, cim_matmul_pallas
+from .cim_matmul import (BLOCK_M_MAX, BLOCK_N_MAX, cim_matmul_experts_pallas,
+                         cim_matmul_pallas)
 
 #: Mesh axis the packed column (output-channel) dimension shards over by
 #: default — the tensor-parallel axis of the serving meshes (launch/serve
@@ -205,8 +206,8 @@ def cim_matmul(
     psum_bits: int,
     psum_quant: bool = True,
     use_kernel: bool = True,
-    block_m: int = 128,
-    block_n: int = 128,
+    block_m: int = BLOCK_M_MAX,
+    block_n: int = BLOCK_N_MAX,
     variation_key=None,
     variation_std=None,
     mesh=None,
@@ -331,8 +332,8 @@ def cim_conv(
     psum_bits: int,
     psum_quant: bool = True,
     use_kernel: bool = True,
-    block_m: int = 128,
-    block_n: int = 128,
+    block_m: int = BLOCK_M_MAX,
+    block_n: int = BLOCK_N_MAX,
     variation_key=None,
     variation_std=None,
     mesh=None,

@@ -9,7 +9,12 @@ events and records them on the registry:
 * ``/jax/compilation_cache/cache_hits`` / ``cache_misses`` ->
   ``jit.cache.hits`` / ``jit.cache.misses``. JAX records a miss when it
   writes the compile to the cache, so a compile faster than the cache's
-  minimum compile time counts in ``jit.compiles`` alone.
+  minimum compile time counts in ``jit.compiles`` alone;
+* the ``cim.grid.steps`` scalar the CIM kernel wrapper
+  (``kernels.cim_matmul.fused_grid_call``) records while it is traced ->
+  the ``cim.grid.steps`` counter. A jitted kernel traces once per
+  distinct signature in a process, so a repeated call with the same
+  shapes counts once, as it compiles once.
 
 Listeners are process-wide: every compile in the process counts, not
 only those of the code that armed the watch. ``close()`` unregisters
@@ -39,6 +44,7 @@ class CompileWatch:
         self._armed = True
         jax.monitoring.register_event_duration_secs_listener(self._duration)
         jax.monitoring.register_event_listener(self._event)
+        jax.monitoring.register_scalar_listener(self._scalar)
 
     def _duration(self, event: str, duration_secs: float, **_) -> None:
         if event == COMPILE_EVENT:
@@ -50,9 +56,14 @@ class CompileWatch:
         if event in CACHE_EVENTS:
             self.registry.counter(CACHE_EVENTS[event]).inc()
 
+    def _scalar(self, event: str, value, **_) -> None:
+        if event == names.CIM_GRID_STEPS:
+            self.registry.counter(names.CIM_GRID_STEPS).inc(int(value))
+
     def close(self) -> None:
         """Unregister the listeners; a second call does nothing."""
         if self._armed:
             self._armed = False
             jax.monitoring.unregister_event_duration_listener(self._duration)
             jax.monitoring.unregister_event_listener(self._event)
+            jax.monitoring.unregister_scalar_listener(self._scalar)

@@ -50,6 +50,10 @@ ADC_SATURATED = "cim.adc.saturated"
 ADC_COL_SATURATION_RATE = "cim.adc.col_saturation_rate"
 #: histogram: per-column mean ADC range occupancy |q|/q_max
 ADC_OCCUPANCY = "cim.adc.occupancy"
+#: counter: grid steps of the CIM kernels traced (``fused_grid_call``
+#: records each kernel's grid size while it is traced, never inside the
+#: compiled program; ``repro.obs.compiles`` counts it)
+CIM_GRID_STEPS = "cim.grid.steps"
 
 # -- compile plane (recorded by repro.obs.compiles, from jax.monitoring) ----
 

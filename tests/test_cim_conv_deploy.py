@@ -184,3 +184,49 @@ def test_layers_conv_specs_and_apply():
     # init_params materializes the deploy specs (zeros planes)
     dparams = init_params(dsp, jax.random.PRNGKey(0))
     assert dparams["w_digits"].dtype == jnp.int8
+
+
+@pytest.mark.parametrize("k,stride", [(3, 1), (3, 2), (1, 2)])
+@pytest.mark.parametrize("psum_bits", [1, 6, None],
+                         ids=["adc1", "adc6", "adc_free"])
+@pytest.mark.parametrize("store", ["int8", "nibble"])
+@pytest.mark.parametrize("skip", [False, True], ids=["dense", "skip"])
+def test_conv_kernel_bit_exact_with_oracle(k, stride, psum_bits, store,
+                                           skip):
+    """The conv deploy kernels equal ``ref.cim_conv_ref`` (ADC-free,
+    ``psum_bits=None``: the unquantized oracle) bit for bit: 128-row
+    arrays of 14 channels x 9 taps (3x3) or 128 channels (1x1), 64
+    output columns (half a lane block), M = 2*9*9 = 162 or 2*5*5 = 50
+    rows in one padded block, and dead planes for the occupancy skip."""
+    from repro.core.nibble import occupancy_map, pack_nibbles
+    from repro.kernels import ref
+    from repro.kernels.cim_adc_free import cim_conv_adc_free_pallas
+    from repro.kernels.cim_conv import cim_conv_pallas
+    cpa = 128 // (k * k)
+    c_in, c_out = 3 * cpa - 5, 64
+    kt = -(-c_in // cpa)
+    ks = jax.random.split(jax.random.PRNGKey(k * 10 + stride), 4)
+    a = jax.random.randint(ks[0], (2, 9, 9, c_in), 0, 64).astype(jnp.int8)
+    digits = jax.random.randint(ks[1], (2, kt, k, k, cpa, c_out), -8, 8)
+    digits = digits.at[0, 1].set(0).astype(jnp.int8)
+    flat = digits.reshape(2, kt, k * k * cpa, c_out)
+    stored = (pack_nibbles(digits).reshape(2, kt, k * k * cpa // 2, c_out)
+              if store == "nibble" else flat)
+    occ = occupancy_map(digits, conv=True) if skip else None
+    s_p = 2.0 ** jax.random.randint(ks[2], (2, kt, c_out), 3, 8)
+    deq = jax.random.uniform(ks[3], (2, kt, c_out), minval=0.01, maxval=0.1)
+    geo = dict(kh=k, kw=k, stride=stride, padding="SAME", c_per_array=cpa)
+    if psum_bits is None:
+        out = cim_conv_adc_free_pallas(a, stored, deq, None, None, occ,
+                                       interpret=True, **geo)
+        a_t = ref.extract_conv_patches(a.astype(jnp.float32), k, k, stride,
+                                       "SAME", kt, cpa)
+        expect = ref.cim_matmul_adc_free_ref(
+            a_t.reshape(-1, kt, k * k * cpa), flat, deq
+        ).reshape(a_t.shape[:3] + (c_out,))
+    else:
+        out = cim_conv_pallas(a, stored, s_p, deq, None, None, occ,
+                              psum_bits=psum_bits, interpret=True, **geo)
+        expect = ref.cim_conv_ref(a, flat, s_p, deq, psum_bits=psum_bits,
+                                  **geo)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(expect))

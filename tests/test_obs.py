@@ -165,6 +165,33 @@ def test_compile_watch_counts_one_compile_per_new_program():
     assert reg.counter(M.JIT_COMPILES).value == 1
 
 
+def test_compile_watch_counts_cim_grid_steps():
+    """Tracing a CIM kernel adds its grid size to ``cim.grid.steps``:
+    (M/bm, N/bn, S, k_tiles/tk) as ``block_shape`` picks it. A second
+    call with the same shapes reuses the trace and adds nothing, and
+    after ``close()`` nothing is counted."""
+    from repro.kernels.cim_matmul import block_shape, cim_matmul_pallas
+    from repro.obs import CompileWatch
+    reg = MetricsRegistry()
+    m, kt, rows, n = 300, 3, 24, 136       # shapes no other test traces
+    a = jnp.ones((m, kt, rows))
+    d = jnp.ones((2, kt, rows, n), jnp.int8)
+    sc = jnp.ones((2, kt, n))
+    watch = CompileWatch(reg)
+    try:
+        cim_matmul_pallas(a, d, sc, sc, psum_bits=4, interpret=True)
+        bm, bn, tk = block_shape(m, n, kt, rows, rows, a.dtype, d.dtype,
+                                 n_cols=2)
+        steps = -(-m // bm) * -(-n // bn) * 2 * -(-kt // tk)
+        assert reg.counter(M.CIM_GRID_STEPS).value == steps == 2
+        cim_matmul_pallas(a, d, sc, sc, psum_bits=4, interpret=True)
+        assert reg.counter(M.CIM_GRID_STEPS).value == steps
+    finally:
+        watch.close()
+    cim_matmul_pallas(a[:200], d, sc, sc, psum_bits=4, interpret=True)
+    assert reg.counter(M.CIM_GRID_STEPS).value == steps
+
+
 # ---------------------------------------------------------------------------
 # ADC saturation collector
 # ---------------------------------------------------------------------------

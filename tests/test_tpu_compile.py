@@ -6,22 +6,28 @@ over a longer axis, a vector reduce it cannot relayout, SMEM misuse.
 These cases compile each kernel of the serving path with
 ``interpret=False`` for one chip of a *described* v5e:2x2 topology (no
 chip attached) and assert the Pallas kernel is in the program
-(``tpu_custom_call``). Nothing runs, so nothing here is a timing.
+(``tpu_custom_call``), and that the grid and VMEM limit Mosaic got are
+the ones ``cim_matmul.block_shape`` picks. Nothing runs, so nothing here
+is a timing.
 
 The topology is described inside a module fixture — never at import —
 so every test worker collects the same tests and only the worker that
 runs this file loads the TPU compiler.
 """
+import base64
+import json
 import os
 import re
 
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.extend.mlir import ir
 
 from repro.kernels.cim_adc_free import cim_matmul_adc_free_pallas
 from repro.kernels.cim_conv import cim_conv_pallas
-from repro.kernels.cim_matmul import (cim_matmul_experts_pallas,
+from repro.kernels.cim_matmul import (VMEM_LIMIT, block_shape,
+                                      cim_matmul_experts_pallas,
                                       cim_matmul_pallas)
 
 ROWS = 128          # CIM array rows of the serving configs
@@ -58,6 +64,43 @@ def _assert_kernel(lowered):
     assert "tpu_custom_call" in text
 
 
+def _kernel_calls(text):
+    """(instruction name, grid, scoped VMEM bytes) of every Pallas call in
+    a compiled module: the grid is the ``iteration_bounds`` of the Mosaic
+    kernel serialized in the call's backend config."""
+    calls = []
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        name = line.split(" = ", 1)[0].split()[-1].lstrip("%")
+        config = line[line.index("backend_config=") + len("backend_config="):]
+        config = json.JSONDecoder().raw_decode(config)[0]
+        body = base64.b64decode(config["custom_call_config"]["body"])
+        with ir.Context() as ctx:
+            ctx.allow_unregistered_dialects = True
+            main = next(op for op in ir.Module.parse(body).body.operations
+                        if str(op.attributes["sym_name"]) == '"main"')
+            grid = tuple(int(b) for b in re.findall(
+                r"-?\d+", str(main.attributes["iteration_bounds"])
+                .split(":", 1)[1]))
+        vmem = int(config["scoped_memory_configs"][0]["size"])
+        calls.append((name, grid, vmem))
+    return calls
+
+
+def _assert_grid(lowered, m, n, k_tiles, rows, rows_d, a_dtype, d_dtype,
+                 n_cols=2):
+    """One ``cim_matmul`` call whose grid is the chooser's, compiled with
+    the kernel's VMEM limit; returns the chosen (bm, bn, tk)."""
+    (name, grid, vmem), = _kernel_calls(lowered.compile().as_text())
+    bm, bn, tk = block_shape(m, n, k_tiles, rows, rows_d, a_dtype, d_dtype,
+                             n_cols=n_cols)
+    assert re.fullmatch(r"cim_(matmul|adc_free)(\.\d+)?", name), name
+    assert grid == (-(-m // bm), -(-n // bn), S, -(-k_tiles // tk)), grid
+    assert vmem == VMEM_LIMIT
+    return bm, bn, tk
+
+
 def _matmul_operands(sh, m, k, n, dtype):
     kt = k // ROWS
     rows_d = ROWS // 2 if dtype == jnp.uint8 else ROWS
@@ -72,17 +115,26 @@ def test_matmul_dense_int8_d_model_to_d_ff(one_chip, m):
     """olmo-1b's up-projections: K=2048 (16 array tiles), N=8192 — at a
     decode batch and at a prefill batch."""
     a, d, sp, dq = _matmul_operands(one_chip, m, 2048, 8192, jnp.int8)
-    _assert_kernel(cim_matmul_pallas.lower(a, d, sp, dq, psum_bits=6,
-                                           interpret=False))
+    bm, _, tk = _assert_grid(
+        cim_matmul_pallas.lower(a, d, sp, dq, psum_bits=6, interpret=False),
+        m, 8192, 16, ROWS, ROWS, jnp.int8, jnp.int8)
+    assert (bm, tk) == (m, 16)
 
 
-def test_matmul_variation_float_digits(one_chip):
+@pytest.mark.parametrize("k", [2048, 8192])
+def test_matmul_variation_float_digits(one_chip, k):
     """One variation realization turns the digit block float32, which
-    the kernel contracts at float32 precision."""
-    a, d, sp, dq = _matmul_operands(one_chip, 8, 2048, 2048, jnp.int8)
+    the kernel contracts at float32 precision. At olmo-1b's
+    down-projection (K=8192, 64 array tiles) the float digits of all
+    tiles do not fit VMEM, so a step takes a chunk of them."""
+    a, d, sp, dq = _matmul_operands(one_chip, 8, k, 2048, jnp.int8)
     key = _spec((2,), jnp.uint32, one_chip)
-    _assert_kernel(cim_matmul_pallas.lower(a, d, sp, dq, key, 0.05,
-                                           psum_bits=6, interpret=False))
+    kt = k // ROWS
+    _, _, tk = _assert_grid(
+        cim_matmul_pallas.lower(a, d, sp, dq, key, 0.05, psum_bits=6,
+                                interpret=False),
+        8, 2048, kt, ROWS, ROWS, jnp.int8, jnp.float32)
+    assert (tk < kt) == (k == 8192), tk
 
 
 @pytest.mark.parametrize("psum_bits", [1, 6])
@@ -92,9 +144,45 @@ def test_matmul_nibble_occupancy_d_ff_to_d_model(one_chip, psum_bits):
     (psum_bits=1) is the case where a skipped block still contributes."""
     a, d, sp, dq = _matmul_operands(one_chip, 8, 8192, 2048, jnp.uint8)
     occ = _spec((S, 8192 // ROWS, 2048), jnp.uint8, one_chip)
-    _assert_kernel(cim_matmul_pallas.lower(a, d, sp, dq, None, None, occ,
-                                           psum_bits=psum_bits,
-                                           interpret=False))
+    _, _, tk = _assert_grid(
+        cim_matmul_pallas.lower(a, d, sp, dq, None, None, occ,
+                                psum_bits=psum_bits, interpret=False),
+        8, 2048, 64, ROWS, ROWS // 2, jnp.int8, jnp.uint8)
+    assert tk == 64
+
+
+#: every distinct CIM conv of ResNet-18 at 224x224 input (stem out):
+#: (input side, C_in, C_out, kernel, stride); 3x3 stride 1, the 3x3
+#: stride-2 ``conv1`` of stages 1-3 and their 1x1 ``proj``
+RESNET18_CONVS = [(56, 64, 64, 3, 1), (28, 128, 128, 3, 1),
+                  (14, 256, 256, 3, 1), (7, 512, 512, 3, 1),
+                  (56, 64, 128, 3, 2), (28, 128, 256, 3, 2),
+                  (14, 256, 512, 3, 2), (56, 64, 128, 1, 2),
+                  (28, 128, 256, 1, 2), (14, 256, 512, 1, 2)]
+
+
+@pytest.mark.parametrize("hw,c_in,c_out,k,stride", RESNET18_CONVS,
+                         ids=[f"{h}x{ci}-{co}_{k}x{k}s{st}"
+                              for h, ci, co, k, st in RESNET18_CONVS])
+def test_conv_resnet18_batch128(one_chip, hw, c_in, c_out, k, stride):
+    """Each ResNet-18 CIM conv at batch 128 on int4 nibble planes with the
+    occupancy map — the benchmark's shapes — is one ``cim_matmul`` call
+    on the grid the chooser picks, with every array tile in one step."""
+    cpa = min(ROWS // (k * k), c_in)
+    kt = -(-c_in // cpa)
+    rows = k * k * cpa
+    a = _spec((128, hw, hw, c_in), jnp.int8, one_chip)
+    d = _spec((S, kt, rows // 2, c_out), jnp.uint8, one_chip)
+    sp = _spec((S, kt, c_out), jnp.float32, one_chip)
+    occ = _spec((S, kt, c_out), jnp.uint8, one_chip)
+    ho = -(-hw // stride)
+    _, _, tk = _assert_grid(
+        cim_conv_pallas.lower(a, d, sp, sp, None, None, occ, kh=k, kw=k,
+                              stride=stride, padding="SAME",
+                              c_per_array=cpa, psum_bits=6,
+                              interpret=False),
+        128 * ho * ho, c_out, kt, rows, rows // 2, jnp.int8, jnp.uint8)
+    assert tk == kt
 
 
 @pytest.mark.parametrize("dtype", [jnp.int8, jnp.uint8],
@@ -109,9 +197,10 @@ def test_conv_resnet18_3x3(one_chip, dtype):
     d = _spec((S, kt, rows_d, 64), dtype, one_chip)
     sp = _spec((S, kt, 64), jnp.float32, one_chip)
     occ = _spec((S, kt, 64), jnp.uint8, one_chip)
-    _assert_kernel(cim_conv_pallas.lower(
+    _assert_grid(cim_conv_pallas.lower(
         a, d, sp, sp, None, None, occ, kh=3, kw=3, stride=1,
-        padding="SAME", c_per_array=cpa, psum_bits=6, interpret=False))
+        padding="SAME", c_per_array=cpa, psum_bits=6, interpret=False),
+        8 * 56 * 56, 64, kt, 9 * cpa, rows_d, jnp.int8, dtype)
 
 
 def test_conv_kernel_names_and_scopes(one_chip):
@@ -140,8 +229,9 @@ def test_conv_kernel_names_and_scopes(one_chip):
 def test_matmul_adc_free_nibble_occupancy(one_chip):
     a, d, _, dq = _matmul_operands(one_chip, 8, 2048, 2048, jnp.uint8)
     occ = _spec((S, 2048 // ROWS, 2048), jnp.uint8, one_chip)
-    _assert_kernel(cim_matmul_adc_free_pallas.lower(
-        a, d, dq, None, None, occ, interpret=False))
+    _assert_grid(cim_matmul_adc_free_pallas.lower(
+        a, d, dq, None, None, occ, interpret=False),
+        8, 2048, 16, ROWS, ROWS // 2, jnp.int8, jnp.uint8, n_cols=1)
 
 
 def test_experts_bank(one_chip):
