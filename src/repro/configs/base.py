@@ -47,9 +47,20 @@ class SSMConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class VisionConfig:
+    """A native-resolution vision tower (family ``vit``): the learned
+    position table is ``pos_grid`` x ``pos_grid`` and is resized to each
+    image's patch grid; ``merge`` x ``merge`` patch neighbourhoods merge
+    into one token, which the projector maps to ``out_dim``."""
+    pos_grid: int = 64
+    merge: int = 2
+    out_dim: int = 2048
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
-    family: str                  # transformer | xlstm | zamba2 | whisper | llava | resnet
+    family: str                  # transformer | xlstm | zamba2 | whisper | llava | vit | resnet
     n_layers: int
     d_model: int
     n_heads: int
@@ -66,12 +77,13 @@ class ModelConfig:
     moe: Optional[MoEConfig] = None
     mla: Optional[MLAConfig] = None
     ssm: Optional[SSMConfig] = None
+    vision: Optional[VisionConfig] = None   # vit: the tower's own geometry
     attn_every: int = 0          # zamba2: shared attn block every N ssm blocks
     enc_layers: int = 0          # whisper encoder layers
     n_frontend_tokens: int = 0   # vlm/audio stub tokens (576 patches / 1500 frames)
     frontend_dim: int = 0        # stub embedding dim (defaults to d_model)
     conv_frontend: bool = False  # real conv frontend (CIM conv kernel) vs stub
-    patch_size: int = 0          # llava conv frontend: square patch edge
+    patch_size: int = 0          # llava conv frontend / vit: square patch edge
     cim: CIMConfig = dataclasses.field(default_factory=CIMConfig)
     cim_lm_head: bool = False    # also CIM-quantize the LM head
     param_dtype: str = "float32"

@@ -36,7 +36,9 @@ def split_digits(w_int: jnp.ndarray, weight_bits: int, cell_bits: int) -> jnp.nd
     base = 2 ** cell_bits
     w = jax.lax.stop_gradient(w_int)
     sign = jnp.sign(w)
-    mag = jnp.abs(w).astype(jnp.int32)
+    # round, not truncate: a code that comes back from a float division
+    # as c - eps must stay c (a cast alone would make it c - 1)
+    mag = jnp.round(jnp.abs(w)).astype(jnp.int32)
     digits = []
     for s in range(s_count):
         digits.append(((mag // (base ** s)) % base).astype(w_int.dtype) * sign)

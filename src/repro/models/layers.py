@@ -175,6 +175,24 @@ def rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
     return out.astype(x.dtype)
 
 
+def rope_2d(x: jnp.ndarray, rows: jnp.ndarray, cols: jnp.ndarray,
+            theta: float) -> jnp.ndarray:
+    """2-D rotary embedding of image patches (MoonViT). x: (..., T, H, hd);
+    rows, cols: (T,) patch positions. The head's hd/2 coordinate pairs
+    are adjacent elements (2j, 2j+1); pair 2i turns by ``cols * w_i``
+    and pair 2i+1 by ``rows * w_i``, w_i = theta^(-4i/hd)."""
+    hd = x.shape[-1]
+    freqs = 1.0 / (theta ** (jnp.arange(0, hd, 4, dtype=jnp.float32) / hd))
+    ang = jnp.stack([cols.astype(jnp.float32)[:, None] * freqs,
+                     rows.astype(jnp.float32)[:, None] * freqs], axis=-1)
+    ang = ang.reshape(ang.shape[0], hd // 2)[:, None, :]      # (T, 1, hd/2)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xr = x.astype(jnp.float32).reshape(x.shape[:-1] + (hd // 2, 2))
+    x1, x2 = xr[..., 0], xr[..., 1]
+    out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
 # ---------------------------------------------------------------------------
 # attention core (full + KV-chunked flash-style)
 # ---------------------------------------------------------------------------

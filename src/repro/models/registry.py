@@ -1,8 +1,10 @@
 """Model registry: family name -> (specs, forward, init_cache, decode_step).
 
-``get_model(cfg)`` resolves the family of a ModelConfig; every entry
-shares the same functional interface so the trainer / server / dry-run
-never special-case architectures.
+``get_model(cfg)`` resolves the family of a ModelConfig; every language
+model entry shares the same functional interface so the trainer / server
+/ dry-run never special-case architectures. The ``vit`` family is an
+encoder: its forward maps a list of images to their projector tokens
+(``vit.forward(params, images, cfg)``) and it has no cache.
 """
 from __future__ import annotations
 
@@ -10,7 +12,7 @@ import dataclasses
 from typing import Callable, Dict, Optional
 
 from repro.configs.base import ModelConfig
-from . import llava, transformer, whisper, xlstm, zamba2
+from . import llava, transformer, vit, whisper, xlstm, zamba2
 
 
 @dataclasses.dataclass(frozen=True)
@@ -32,6 +34,7 @@ _FAMILIES: Dict[str, ModelFns] = {
                         whisper.decode_step),
     "llava": ModelFns(llava.specs, llava.forward, llava.init_cache,
                       llava.decode_step),
+    "vit": ModelFns(vit.specs, vit.forward, None, None),
 }
 
 

@@ -14,7 +14,10 @@ events and records them on the registry:
   (``kernels.cim_matmul.fused_grid_call``) records while it is traced ->
   the ``cim.grid.steps`` counter. A jitted kernel traces once per
   distinct signature in a process, so a repeated call with the same
-  shapes counts once, as it compiles once.
+  shapes counts once, as it compiles once;
+* the ``vit.attn.pairs`` scalar the vision tower (``models.vit``)
+  records while it is traced -> the ``vit.attn.pairs`` counter, once
+  per traced forward.
 
 Listeners are process-wide: every compile in the process counts, not
 only those of the code that armed the watch. ``close()`` unregisters
@@ -57,8 +60,8 @@ class CompileWatch:
             self.registry.counter(CACHE_EVENTS[event]).inc()
 
     def _scalar(self, event: str, value, **_) -> None:
-        if event == names.CIM_GRID_STEPS:
-            self.registry.counter(names.CIM_GRID_STEPS).inc(int(value))
+        if event in (names.CIM_GRID_STEPS, names.VIT_ATTN_PAIRS):
+            self.registry.counter(event).inc(int(value))
 
     def close(self) -> None:
         """Unregister the listeners; a second call does nothing."""

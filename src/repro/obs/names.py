@@ -55,6 +55,13 @@ ADC_OCCUPANCY = "cim.adc.occupancy"
 #: compiled program; ``repro.obs.compiles`` counts it)
 CIM_GRID_STEPS = "cim.grid.steps"
 
+# -- vision plane (recorded by repro.models.vit while it is traced) ---------
+
+#: counter: query-key pairs the vision tower's attention covers, the sum
+#: of n_i^2 over the images of a traced forward (attention stays within
+#: each image; one mask over the whole pack would cover (sum n_i)^2)
+VIT_ATTN_PAIRS = "vit.attn.pairs"
+
 # -- compile plane (recorded by repro.obs.compiles, from jax.monitoring) ----
 
 #: counter: XLA backend compile requests — a fresh compile or a load from
@@ -93,6 +100,16 @@ SCOPE_RESNET_STEM = "resnet.stem"
 SCOPE_RESNET_BLOCK = "resnet.s{stage}b{block}"
 #: scope: the ResNet head (average pool and classifier)
 SCOPE_RESNET_HEAD = "resnet.head"
+#: scope: the vision tower's float patch embedding and position table
+SCOPE_VIT_PATCH_EMBED = "vit.patch_embed"
+#: scope: one vision-tower block (its CIM linears keep their ``cim.*``
+#: scopes inside it)
+SCOPE_VIT_BLOCK = "vit.block"
+#: scope: the vision tower's attention core between the CIM linears:
+#: 2-D RoPE, QK, softmax and AV, per image
+SCOPE_VIT_ATTN = "vit.attn"
+#: scope: the 2x2 patch merge before the projector
+SCOPE_VIT_MERGE = "vit.merge"
 
 # -- Pallas kernel names (``pallas_call(name=...)``) -------------------------
 #

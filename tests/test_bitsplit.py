@@ -1,6 +1,7 @@
 """Bit-split decomposition properties (paper Fig. 5)."""
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.core.bitsplit import place_values, recombine, split_digits
@@ -94,3 +95,34 @@ def test_binary_weight_single_split():
     d = split_digits(w, 1, 1)
     assert d.shape == (1, 3)
     assert np.array_equal(np.asarray(recombine(d, 1, 1)), np.asarray(w))
+
+
+@pytest.mark.parametrize("pack_dtype", ["int8", "int4"])
+def test_packed_codes_equal_rounded_quotient(pack_dtype):
+    """With float32 column scales ``w / s`` comes back from the quantizer
+    a last bit below an integer code about as often as above it; the
+    pack keeps the nearest integer, which float64 ``round(w / s)``
+    witnesses (a truncating pack loses a code in about 1% of them on
+    the CPU)."""
+    from repro.core.cim_linear import (CIMConfig, _pack_linear,
+                                       weight_scales_from)
+    from repro.core.nibble import is_nibble_packed, unpack_nibbles
+    k, n = 300, 256
+    cfg = CIMConfig(enabled=True, weight_bits=4, cell_bits=2,
+                    pack_dtype=pack_dtype)
+    rng = np.random.RandomState(0)
+    w = rng.randn(k, n).astype(np.float32) / np.sqrt(k)
+    s_w = weight_scales_from(jnp.asarray(w), cfg)
+    t = cfg.tiling(k, n)
+    packed = _pack_linear({"w": jnp.asarray(w), "s_w": s_w,
+                           "s_p": jnp.ones(t.psum_scale_shape(
+                               cfg.psum_granularity)),
+                           "s_a": jnp.ones((1,))}, cfg)
+    d = packed["w_digits"]
+    if is_nibble_packed(d):
+        d = unpack_nibbles(d)
+    codes = np.asarray(recombine(d.astype(jnp.float32), 4, 2))
+    codes = codes.reshape(t.k_padded, n)[:k]
+    s = np.repeat(np.asarray(s_w, np.float64), t.array_rows, axis=0)[:k]
+    want = np.clip(np.round(w.astype(np.float64) / s), -8, 7)
+    assert np.array_equal(codes, want)

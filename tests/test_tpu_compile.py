@@ -102,7 +102,7 @@ def _assert_grid(lowered, m, n, k_tiles, rows, rows_d, a_dtype, d_dtype,
 
 
 def _matmul_operands(sh, m, k, n, dtype):
-    kt = k // ROWS
+    kt = -(-k // ROWS)
     rows_d = ROWS // 2 if dtype == jnp.uint8 else ROWS
     return (_spec((m, kt, ROWS), jnp.int8, sh),
             _spec((S, kt, rows_d, n), dtype, sh),
@@ -149,6 +149,29 @@ def test_matmul_nibble_occupancy_d_ff_to_d_model(one_chip, psum_bits):
                                 psum_bits=psum_bits, interpret=False),
         8, 2048, 64, ROWS, ROWS // 2, jnp.int8, jnp.uint8)
     assert tk == 64
+
+
+#: every CIM linear of Kimi-VL's MoonViT tower on the benchmark's pack of
+#: 16,384 patches, (M, K, N): a block's fused QKV, output, MLP up and MLP
+#: down (K = 4304: 34 array tiles, the last one part-filled), then the
+#: projector's two at the 4,096 merged tokens
+MOONVIT_LINEARS = [(16384, 1152, 3456), (16384, 1152, 1152),
+                   (16384, 1152, 4304), (16384, 4304, 1152),
+                   (4096, 4608, 4608), (4096, 4608, 2048)]
+
+
+@pytest.mark.parametrize("m,k,n", MOONVIT_LINEARS,
+                         ids=[f"{m}x{k}-{n}" for m, k, n in MOONVIT_LINEARS])
+def test_matmul_nibble_occupancy_moonvit(one_chip, m, k, n):
+    """The deploy path's call for each tower linear: int4 nibble planes
+    with the occupancy table, as ``model_artifact`` packs them."""
+    kt = -(-k // ROWS)
+    a, d, sp, dq = _matmul_operands(one_chip, m, k, n, jnp.uint8)
+    occ = _spec((S, kt, n), jnp.uint8, one_chip)
+    _assert_grid(
+        cim_matmul_pallas.lower(a, d, sp, dq, None, None, occ, psum_bits=6,
+                                interpret=False),
+        m, n, kt, ROWS, ROWS // 2, jnp.int8, jnp.uint8)
 
 
 #: every distinct CIM conv of ResNet-18 at 224x224 input (stem out):

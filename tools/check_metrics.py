@@ -6,7 +6,7 @@ a table; the single source of truth for those names is
 ``src/repro/obs/names.py``. This lint holds the two together, both
 ways, statically (ast — no jax import needed):
 
-* every ``serve.*`` / ``cim.*`` / ``jit.*`` / ``resnet.*`` name and
+* every ``serve.*`` / ``cim.*`` / ``jit.*`` / ``resnet.*`` / ``vit.*`` name and
   every ``cim_*`` kernel name appearing in DESIGN.md §12 must be the
   value of a constant in ``repro/obs/names.py``;
 * every constant in ``names.py`` must appear in the §12 table.
@@ -28,7 +28,7 @@ DESIGN = REPO / "DESIGN.md"
 #: `serve.queue.depth` or `resnet.s{stage}b{block}`, and kernel names
 #: such as `cim_matmul`
 NAME_RE = re.compile(
-    r"`((?:serve|cim|jit|resnet)\.[a-z0-9_.{}]+|cim_[a-z0-9_]+)`")
+    r"`((?:serve|cim|jit|resnet|vit)\.[a-z0-9_.{}]+|cim_[a-z0-9_]+)`")
 
 
 def declared_names() -> set[str]:
