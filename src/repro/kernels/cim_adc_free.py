@@ -39,8 +39,8 @@ import jax.numpy as jnp
 
 from repro.obs import names
 
-from .cim_matmul import BLOCK_M_MAX, BLOCK_N_MAX, fused_grid_call
-from .ref import extract_conv_patches
+from .cim_conv import nhwc_output, tile_major_patches
+from .cim_matmul import BLOCK_M_MAX, BLOCK_N_MAX, fused_grid_call, tile_major
 
 
 def _digital(p, deq):
@@ -51,6 +51,23 @@ def _digital(p, deq):
     here: an all-zero plane's exact digital psum is 0, so dense and skip
     both add +0.0 — bit-identical on a +0.0-initialized accumulator."""
     return jnp.round(p) * deq.astype(jnp.float32)[None, :]
+
+
+def _adc_free_tiled(a_tm, digits, deq, variation_key, variation_std, occ,
+                    *, nibble_groups: int, block_m: int, block_n: int,
+                    interpret: bool) -> jnp.ndarray:
+    """The ADC-free kernel on tile-major activations (k_tiles, M, rows),
+    shared by the matmul and conv entry points. Returns (M, N) float32."""
+    # reduction dims (s outer, t inner), iterating fastest: the digital
+    # accumulator adds the dequantized words in the order of the ADC
+    # kernel and of the oracle (``ref.shift_add``) — unquantized psums
+    # carry full mantissas, so any reassociation is visible at 1 ulp and
+    # amplifies through the next layer's activation-code rounding
+    return fused_grid_call(_digital, a_tm, digits, ((deq, 0.0),),
+                           variation_key, variation_std, occ,
+                           name=names.KERNEL_CIM_ADC_FREE,
+                           nibble_groups=nibble_groups, block_m=block_m,
+                           block_n=block_n, interpret=interpret)
 
 
 @functools.partial(
@@ -76,16 +93,12 @@ def cim_matmul_adc_free_pallas(
     ``s_p`` (no ADC scale stream exists on this hardware style). Returns
     (M, N) float32.
     """
-    # reduction dims (s outer, t inner), iterating fastest: the digital
-    # accumulator adds the dequantized words in the order of the ADC
-    # kernel and of the oracle (``ref.shift_add``) — unquantized psums
-    # carry full mantissas, so any reassociation is visible at 1 ulp and
-    # amplifies through the next layer's activation-code rounding
-    return fused_grid_call(_digital, a_t, digits, ((deq, 0.0),),
-                           variation_key, variation_std, occ,
-                           name=names.KERNEL_CIM_ADC_FREE,
-                           nibble_groups=nibble_groups, block_m=block_m,
-                           block_n=block_n, interpret=interpret)
+    with jax.named_scope(names.SCOPE_LAYOUT):
+        a_tm = tile_major(a_t)
+    return _adc_free_tiled(a_tm, digits, deq, variation_key, variation_std,
+                           occ, nibble_groups=nibble_groups,
+                           block_m=block_m, block_n=block_n,
+                           interpret=interpret)
 
 
 @functools.partial(
@@ -111,25 +124,23 @@ def cim_conv_adc_free_pallas(
     interpret: bool = False,
 ) -> jnp.ndarray:
     """ADC-free CIM conv: same stretched-kernel lowering as
-    ``kernels.cim_conv.cim_conv_pallas`` (patches once, flatten spatial
-    to M, run the tiled matmul grid) but onto the ADC-free kernel.
+    ``kernels.cim_conv.cim_conv_pallas`` (tile-major patches once, then
+    the tiled matmul grid) but onto the ADC-free kernel.
 
     Returns (B, H', W', C_out) float32.
     """
-    n_split, k_tiles, rows_d, n = digits.shape
+    k_tiles, rows_d = digits.shape[1:3]
     rows = kh * kw * c_per_array           # logical rows, from the geometry
     nibble = digits.dtype == jnp.uint8
     assert rows_d == (rows // 2 if nibble else rows), \
         (digits.shape, kh, kw, c_per_array, nibble)
-    a_t = extract_conv_patches(a_int, kh, kw, stride, padding, k_tiles,
-                               c_per_array)
-    b, ho, wo = a_t.shape[:3]
-    out = cim_matmul_adc_free_pallas(
-        a_t.reshape(b * ho * wo, k_tiles, rows),
-        digits, deq, variation_key, variation_std, occ,
+    a_tm = tile_major_patches(a_int, kh, kw, stride, padding, k_tiles,
+                              c_per_array)
+    out = _adc_free_tiled(
+        a_tm, digits, deq, variation_key, variation_std, occ,
         # each of the kh*kw taps is its own packed nibble block in the
         # flattened row layout (repro.core.nibble)
         nibble_groups=kh * kw,
         block_m=block_m, block_n=block_n, interpret=interpret,
     )
-    return out.reshape(b, ho, wo, n)
+    return nhwc_output(out, a_int.shape, kh, kw, stride, padding)

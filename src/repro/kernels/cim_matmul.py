@@ -46,8 +46,10 @@ reproduce ``jax.random.normal`` draws). The psum-in-VMEM fusion is
 unchanged; the digit operand streams as float32 instead of int8 for the
 duration of the noisy evaluation.
 
-Layout (what makes the grid lower on TPU at any k_tiles): the wrapper
-lays activations out tile-major, (k_tiles, M, rows), and every
+Layout (what makes the grid lower on TPU at any k_tiles): activations
+reach the grid tile-major, (k_tiles, M, rows) — the linear entry point
+swaps its (M, k_tiles, rows) operand, the conv entry points build their
+patches in that layout (kernels/cim_conv) — and every
 per-(split, tile, column) vector — ``s_p``, ``deq`` — as (S, k_tiles,
 1, N). Every block then keeps its last two dims either whole or
 (8, 128)-aligned: the array-tile and bit-split indices only ever select
@@ -203,15 +205,20 @@ def _grid_body(*refs, epilogue, n_cols: int, nibble: bool, groups: int,
     jax.lax.fori_loop(0, tk, step, 0)
 
 
-def tile_major(a_t: jnp.ndarray, bm: int) -> jnp.ndarray:
-    """(..., M, k_tiles, rows) activations -> (..., k_tiles, M', rows),
-    M padded up to a multiple of ``bm`` — the layout whose (1, bm, rows)
-    blocks lower on TPU for any k_tiles."""
-    pad_m = (-a_t.shape[-3]) % bm
-    if pad_m:
-        a_t = jnp.pad(a_t, [(0, 0)] * (a_t.ndim - 3)
-                      + [(0, pad_m), (0, 0), (0, 0)])
+def tile_major(a_t: jnp.ndarray) -> jnp.ndarray:
+    """(..., M, k_tiles, rows) activations -> (..., k_tiles, M, rows): the
+    layout whose (tk, bm, rows) blocks lower on TPU for any k_tiles."""
     return jnp.swapaxes(a_t, -3, -2)
+
+
+def pad_rows(a_tm: jnp.ndarray, bm: int) -> jnp.ndarray:
+    """Tile-major (..., k_tiles, M, rows) activations with M padded up to a
+    multiple of ``bm``; as they are when ``bm`` divides M."""
+    pad_m = (-a_tm.shape[-2]) % bm
+    if pad_m:
+        a_tm = jnp.pad(a_tm, [(0, 0)] * (a_tm.ndim - 2)
+                       + [(0, pad_m), (0, 0)])
+    return a_tm
 
 
 def scale_slab(x: jnp.ndarray, pad_n: int, value: float = 0.0):
@@ -329,15 +336,19 @@ def block_shape(m: int, n: int, k_tiles: int, rows: int, rows_d: int,
     return bm, bn, tk
 
 
-def fused_grid_call(epilogue, a_t, digits, cols, variation_key,
+def fused_grid_call(epilogue, a_tm, digits, cols, variation_key,
                     variation_std, occ, *, name: str, nibble_groups: int,
                     block_m: int, block_n: int,
                     interpret: bool) -> jnp.ndarray:
     """The wrapper the ADC and ADC-free deploy kernels share: variation,
-    padding, the tile-major layout, the block shape (``block_shape``,
-    with ``block_m`` / ``block_n`` as upper bounds), the grid and the
-    occupancy table. The relayout runs under the ``cim.layout`` scope
-    and the kernel, named ``name`` in the trace, under ``cim.kernel``.
+    padding, the block shape (``block_shape``, with ``block_m`` /
+    ``block_n`` as upper bounds), the grid and the occupancy table.
+    ``a_tm`` is already tile-major, (k_tiles, M, rows): the linear entry
+    points swap their (M, k_tiles, rows) operand (``tile_major``), the
+    conv entry points build their patches in that layout
+    (``kernels/cim_conv.tile_major_patches``). Padding runs under the
+    ``cim.layout`` scope and the kernel, named ``name`` in the trace,
+    under ``cim.kernel``.
 
     ``epilogue(p, *cols)`` turns one array tile's psum block into the
     term it adds (``_grid_body``). ``cols`` holds (S, k_tiles, N)
@@ -354,20 +365,20 @@ def fused_grid_call(epilogue, a_t, digits, cols, variation_key,
             digits = unpack_nibbles(digits, groups=nibble_groups)
             nibble = False
         digits = perturb_digits(digits, variation_key, variation_std)
-    m, k_tiles, rows = a_t.shape
+    k_tiles, m, rows = a_tm.shape
     n_split = digits.shape[0]
     n = digits.shape[-1]
     rows_d = digits.shape[2]             # stored rows: rows/2 when nibble
     assert rows_d == (rows // 2 if nibble else rows), \
-        (digits.shape, a_t.shape, nibble)
+        (digits.shape, a_tm.shape, nibble)
 
-    bm, bn, tk = block_shape(m, n, k_tiles, rows, rows_d, a_t.dtype,
+    bm, bn, tk = block_shape(m, n, k_tiles, rows, rows_d, a_tm.dtype,
                              digits.dtype, n_cols=len(cols),
                              block_m=block_m, block_n=block_n)
     pad_n = (-n) % bn
     np_ = n + pad_n
     with jax.named_scope(names.SCOPE_LAYOUT):
-        a_t = tile_major(a_t, bm)        # (k_tiles, mp, rows)
+        a_tm = pad_rows(a_tm, bm)        # (k_tiles, mp, rows)
         if pad_n:
             digits = jnp.pad(digits, ((0, 0), (0, 0), (0, 0), (0, pad_n)))
             if occ is not None:
@@ -375,7 +386,7 @@ def fused_grid_call(epilogue, a_t, digits, cols, variation_key,
         cols = tuple(scale_slab(c, pad_n, value).reshape(
             n_split, k_tiles, 1, np_) for c, value in cols)
         table = None if occ is None else block_occupancy(occ, bn)
-    mp = a_t.shape[1]
+    mp = a_tm.shape[1]
 
     grid = (mp // bm, np_ // bn, n_split, pl.cdiv(k_tiles, tk))
     jax.monitoring.record_scalar(names.CIM_GRID_STEPS, math.prod(grid))
@@ -391,7 +402,7 @@ def fused_grid_call(epilogue, a_t, digits, cols, variation_key,
         _grid_body, epilogue=epilogue, n_cols=len(cols), nibble=nibble,
         groups=nibble_groups, tk=tk, k_tiles=k_tiles,
         row_chunk=_row_chunk(bm, bn))
-    args = (a_t, digits) + cols
+    args = (a_tm, digits) + cols
     if table is None:
         grid_spec = pl.GridSpec(grid=grid, in_specs=in_specs,
                                 out_specs=out_spec)
@@ -415,6 +426,36 @@ def fused_grid_call(epilogue, a_t, digits, cols, variation_key,
         return out[:m, :n]
 
 
+def cim_matmul_tiled(
+    a_tm: jnp.ndarray,     # (k_tiles, M, rows) integer-valued, tile-major
+    digits: jnp.ndarray,   # (S, k_tiles, rows, N); uint8 = nibble-packed
+    s_p: jnp.ndarray,      # (S, k_tiles, N)
+    deq: jnp.ndarray,      # (S, k_tiles, N)
+    variation_key=None,
+    variation_std=None,
+    occ=None,
+    *,
+    psum_bits: int,
+    psum_quant: bool,
+    nibble_groups: int,
+    block_m: int,
+    block_n: int,
+    interpret: bool,
+) -> jnp.ndarray:
+    """The ADC kernel on tile-major activations, shared by
+    ``cim_matmul_pallas`` and the conv entry point
+    (``kernels/cim_conv``). Returns (M, N) float32."""
+    # padded columns: s_p 1.0 keeps the ADC's divide finite, deq 0.0
+    # zeroes them
+    return fused_grid_call(
+        functools.partial(_contribution, psum_bits=psum_bits,
+                          psum_quant=psum_quant),
+        a_tm, digits, ((s_p, 1.0), (deq, 0.0)), variation_key,
+        variation_std, occ, name=names.KERNEL_CIM_MATMUL,
+        nibble_groups=nibble_groups, block_m=block_m, block_n=block_n,
+        interpret=interpret)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("psum_bits", "psum_quant", "nibble_groups", "block_m",
@@ -436,13 +477,11 @@ def cim_matmul_pallas(
     block_n: int = BLOCK_N_MAX,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    # padded columns: s_p 1.0 keeps the ADC's divide finite, deq 0.0
-    # zeroes them
-    return fused_grid_call(
-        functools.partial(_contribution, psum_bits=psum_bits,
-                          psum_quant=psum_quant),
-        a_t, digits, ((s_p, 1.0), (deq, 0.0)), variation_key,
-        variation_std, occ, name=names.KERNEL_CIM_MATMUL,
+    with jax.named_scope(names.SCOPE_LAYOUT):
+        a_tm = tile_major(a_t)
+    return cim_matmul_tiled(
+        a_tm, digits, s_p, deq, variation_key, variation_std, occ,
+        psum_bits=psum_bits, psum_quant=psum_quant,
         nibble_groups=nibble_groups, block_m=block_m, block_n=block_n,
         interpret=interpret)
 
@@ -501,7 +540,7 @@ def cim_matmul_experts_pallas(
     bn = min(block_n, n)
     pad_n = (-n) % bn
     with jax.named_scope(names.SCOPE_LAYOUT):
-        a_t = tile_major(a_t, bm)        # (E, k_tiles, mp, rows)
+        a_t = pad_rows(tile_major(a_t), bm)  # (E, k_tiles, mp, rows)
         if pad_n:
             digits = jnp.pad(digits,
                              ((0, 0), (0, 0), (0, 0), (0, 0), (0, pad_n)))

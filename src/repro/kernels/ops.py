@@ -37,6 +37,8 @@ trace-time decision — see ``repro.obs.adc``.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -120,22 +122,20 @@ def _record_saturation(a2, digits, s_p, *, psum_bits, variation_key,
     obs_adc.record(psum, s_p, psum_bits)
 
 
-def _cim_matmul_sharded(
-    a2, digits, s_p, deq, mesh, mesh_axis, *,
-    psum_bits, psum_quant, use_kernel, block_m, block_n,
-    variation_key, variation_std, adc_free=False, occ=None,
-    nibble_groups=1,
-):
-    """Column-parallel CIM matmul: one kernel shard per device.
+def _sharded(shard_op, a, digits, s_p, deq, mesh, mesh_axis, *,
+             use_kernel, variation_key, variation_std, occ=None,
+             nibble_groups=1):
+    """Column-parallel dispatch: ``shard_op(a, d, sp, dq, occ)`` runs on
+    every device with its own column shard of the planes.
 
-    a2 (M, k_tiles, rows) is replicated; digits/s_p/deq (and the optional
-    occupancy map) shard over their last (column) axis. Nibble-packed
-    uint8 planes stream through shard_map at their packed byte width —
-    the column axis is never the packed axis, so shard boundaries are
-    byte-aligned by construction. No partial sum crosses a device
-    boundary — the reduction dims (array tile, bit-split) live inside
-    each shard's grid — so the single collective is the all-gather of
-    (M, N/D) f32 outputs.
+    ``a`` is replicated; digits/s_p/deq (and the optional occupancy map)
+    shard over their last (column) axis. Nibble-packed uint8 planes
+    stream through shard_map at their packed byte width — the column
+    axis is never the packed axis, so shard boundaries are byte-aligned
+    by construction. No partial sum crosses a device boundary — the
+    reduction dims (array tile, bit-split) live inside each shard's grid
+    — so the single collective is the all-gather of the (..., N/D) f32
+    outputs along their last axis.
     """
     from jax.sharding import PartitionSpec as P
 
@@ -158,33 +158,13 @@ def _cim_matmul_sharded(
     digits, s_p, deq, occ = pad_cols(digits, s_p, deq, n_shards, occ)
 
     def local(a_, d_, sp_, dq_, *rest):
-        occ_ = rest[0] if rest else None
-        if adc_free:
-            # ADC-free style (DESIGN.md §13): no s_p stream — sp_ rides
-            # the shard_map signature so the specs stay uniform, unused
-            if use_kernel:
-                out = cim_matmul_adc_free_pallas(
-                    a_, d_, dq_, None, None, occ_,
-                    nibble_groups=nibble_groups,
-                    block_m=block_m, block_n=block_n,
-                    interpret=interpret_mode())
-            else:
-                out = ref.cim_matmul_adc_free_ref(a_, d_, dq_)
-        elif use_kernel:
-            out = cim_matmul_pallas(
-                a_, d_, sp_, dq_, None, None, occ_,
-                psum_bits=psum_bits, psum_quant=psum_quant,
-                nibble_groups=nibble_groups,
-                block_m=block_m, block_n=block_n,
-                interpret=interpret_mode())
-        else:
-            out = ref.cim_matmul_ref(a_, d_, sp_, dq_, psum_bits=psum_bits,
-                                     psum_quant=psum_quant)
-        return jax.lax.all_gather(out, mesh_axis, axis=1, tiled=True)
+        out = shard_op(a_, d_, sp_, dq_, rest[0] if rest else None)
+        return jax.lax.all_gather(out, mesh_axis, axis=out.ndim - 1,
+                                  tiled=True)
 
     col = P(*([None] * (digits.ndim - 1) + [mesh_axis]))
     col3 = P(None, None, mesh_axis)
-    args = (a2, digits, s_p, deq)
+    args = (a, digits, s_p, deq)
     in_specs = (P(), col, col3, col3)
     if occ is not None and use_kernel:
         args += (occ.astype(jnp.uint8),)
@@ -194,7 +174,30 @@ def _cim_matmul_sharded(
         in_specs=in_specs,
         out_specs=P(), check_vma=False,
     )(*args)
-    return out[:, :n]
+    return out[..., :n]
+
+
+def _matmul_shard(a_, d_, sp_, dq_, occ_, *, psum_bits, psum_quant,
+                  use_kernel, block_m, block_n, adc_free):
+    """One device's CIM matmul over the columns of its shard (``_sharded``:
+    variation already drawn, planes logical on the oracle paths)."""
+    if adc_free:
+        # ADC-free style (DESIGN.md §13): no s_p stream — sp_ rides the
+        # shard_map signature so the specs stay uniform, unused
+        if use_kernel:
+            return cim_matmul_adc_free_pallas(
+                a_, d_, dq_, None, None, occ_,
+                block_m=block_m, block_n=block_n,
+                interpret=interpret_mode())
+        return ref.cim_matmul_adc_free_ref(a_, d_, dq_)
+    if use_kernel:
+        return cim_matmul_pallas(
+            a_, d_, sp_, dq_, None, None, occ_,
+            psum_bits=psum_bits, psum_quant=psum_quant,
+            block_m=block_m, block_n=block_n,
+            interpret=interpret_mode())
+    return ref.cim_matmul_ref(a_, d_, sp_, dq_, psum_bits=psum_bits,
+                              psum_quant=psum_quant)
 
 
 def cim_matmul(
@@ -244,12 +247,14 @@ def cim_matmul(
                            variation_key=variation_key,
                            variation_std=variation_std)
     if col_shards(mesh, mesh_axis) > 1:
-        out = _cim_matmul_sharded(
-            a2, digits, s_p, deq, mesh, mesh_axis,
-            psum_bits=psum_bits, psum_quant=psum_quant,
-            use_kernel=use_kernel, block_m=block_m, block_n=block_n,
+        out = _sharded(
+            functools.partial(
+                _matmul_shard, psum_bits=psum_bits, psum_quant=psum_quant,
+                use_kernel=use_kernel, block_m=block_m, block_n=block_n,
+                adc_free=adc_free),
+            a2, digits, s_p, deq, mesh, mesh_axis, use_kernel=use_kernel,
             variation_key=variation_key, variation_std=variation_std,
-            adc_free=adc_free, occ=occ)
+            occ=occ)
     elif adc_free and use_kernel:
         out = cim_matmul_adc_free_pallas(
             a2, digits, deq, variation_key, variation_std, occ,
@@ -372,34 +377,45 @@ def cim_conv(
             digits, s_p, psum_bits=psum_bits,
             variation_key=variation_key, variation_std=variation_std,
             nibble_groups=kh * kw)
+    run = functools.partial(
+        _conv_dispatch, kh=kh, kw=kw, stride=stride, padding=padding,
+        c_per_array=c_per_array, psum_bits=psum_bits,
+        psum_quant=psum_quant, use_kernel=use_kernel, block_m=block_m,
+        block_n=block_n, adc_free=adc_free)
     if col_shards(mesh, mesh_axis) > 1:
-        # same lowering as cim_conv_pallas: patches once (replicated),
-        # then the column-parallel matmul grid over the C_out shards
-        k_tiles = digits.shape[1]
-        rows = kh * kw * c_per_array    # logical rows, from the geometry
-        a_t = ref.extract_conv_patches(a_int, kh, kw, stride, padding,
-                                       k_tiles, c_per_array)
-        b, ho, wo = a_t.shape[:3]
-        out = _cim_matmul_sharded(
-            a_t.reshape(b * ho * wo, k_tiles, rows), digits, s_p, deq,
-            mesh, mesh_axis, psum_bits=psum_bits, psum_quant=psum_quant,
-            use_kernel=use_kernel, block_m=block_m, block_n=block_n,
-            variation_key=variation_key, variation_std=variation_std,
-            adc_free=adc_free, occ=occ, nibble_groups=kh * kw)
-        return out.reshape(b, ho, wo, digits.shape[-1])
-    if adc_free and use_kernel:
-        return cim_conv_adc_free_pallas(
-            a_int, digits, deq, variation_key, variation_std, occ,
-            kh=kh, kw=kw, stride=stride, padding=padding,
-            c_per_array=c_per_array,
-            block_m=block_m, block_n=block_n,
-            interpret=interpret_mode(),
-        )
+        # patches are output-channel-independent: every device runs the
+        # same conv dispatch on the replicated codes and its C_out shard
+        return _sharded(
+            run, a_int, digits, s_p, deq, mesh, mesh_axis,
+            use_kernel=use_kernel, variation_key=variation_key,
+            variation_std=variation_std, occ=occ, nibble_groups=kh * kw)
+    return run(a_int, digits, s_p, deq, occ, variation_key, variation_std)
+
+
+def _conv_dispatch(a_int, digits, s_p, deq, occ=None, variation_key=None,
+                   variation_std=None, *, kh, kw, stride, padding,
+                   c_per_array, psum_bits, psum_quant, use_kernel, block_m,
+                   block_n, adc_free):
+    """One device's CIM conv over the columns ``digits`` holds: the conv
+    kernels (patches built tile-major, ``kernels/cim_conv``), else the
+    jnp oracles on logical planes."""
+    geo = dict(kh=kh, kw=kw, stride=stride, padding=padding,
+               c_per_array=c_per_array)
+    if use_kernel:
+        blocks = dict(block_m=block_m, block_n=block_n,
+                      interpret=interpret_mode(), **geo)
+        if adc_free:
+            return cim_conv_adc_free_pallas(
+                a_int, digits, deq, variation_key, variation_std, occ,
+                **blocks)
+        return cim_conv_pallas(
+            a_int, digits, s_p, deq, variation_key, variation_std, occ,
+            psum_bits=psum_bits, psum_quant=psum_quant, **blocks)
+    if digits.dtype == jnp.uint8:
+        digits = unpack_nibbles(digits, groups=kh * kw)
+    if variation_wanted(variation_key, variation_std):
+        digits = perturb_digits(digits, variation_key, variation_std)
     if adc_free:
-        if digits.dtype == jnp.uint8:
-            digits = unpack_nibbles(digits, groups=kh * kw)
-        if variation_wanted(variation_key, variation_std):
-            digits = perturb_digits(digits, variation_key, variation_std)
         k_tiles, rows = digits.shape[1], digits.shape[2]
         a_t = ref.extract_conv_patches(a_int.astype(jnp.float32), kh, kw,
                                        stride, padding, k_tiles,
@@ -408,22 +424,5 @@ def cim_conv(
         out = ref.cim_matmul_adc_free_ref(
             a_t.reshape(b * ho * wo, k_tiles, rows), digits, deq)
         return out.reshape(b, ho, wo, digits.shape[-1])
-    if use_kernel:
-        return cim_conv_pallas(
-            a_int, digits, s_p, deq, variation_key, variation_std, occ,
-            kh=kh, kw=kw, stride=stride, padding=padding,
-            c_per_array=c_per_array,
-            psum_bits=psum_bits, psum_quant=psum_quant,
-            block_m=block_m, block_n=block_n,
-            interpret=interpret_mode(),
-        )
-    if digits.dtype == jnp.uint8:
-        digits = unpack_nibbles(digits, groups=kh * kw)
-    if variation_wanted(variation_key, variation_std):
-        digits = perturb_digits(digits, variation_key, variation_std)
-    return ref.cim_conv_ref(
-        a_int, digits, s_p, deq,
-        kh=kh, kw=kw, stride=stride, padding=padding,
-        c_per_array=c_per_array,
-        psum_bits=psum_bits, psum_quant=psum_quant,
-    )
+    return ref.cim_conv_ref(a_int, digits, s_p, deq, psum_bits=psum_bits,
+                            psum_quant=psum_quant, **geo)

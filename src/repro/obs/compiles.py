@@ -15,6 +15,9 @@ events and records them on the registry:
   the ``cim.grid.steps`` counter. A jitted kernel traces once per
   distinct signature in a process, so a repeated call with the same
   shapes counts once, as it compiles once;
+* the ``cim.patches.bytes`` scalar the conv patch builder
+  (``kernels.cim_conv.tile_major_patches``) records while it is traced
+  -> the ``cim.patches.bytes`` counter, once per traced conv;
 * the ``vit.attn.pairs`` scalar the vision tower (``models.vit``)
   records while it is traced -> the ``vit.attn.pairs`` counter, once
   per traced forward.
@@ -60,7 +63,8 @@ class CompileWatch:
             self.registry.counter(CACHE_EVENTS[event]).inc()
 
     def _scalar(self, event: str, value, **_) -> None:
-        if event in (names.CIM_GRID_STEPS, names.VIT_ATTN_PAIRS):
+        if event in (names.CIM_GRID_STEPS, names.CIM_PATCHES_BYTES,
+                     names.VIT_ATTN_PAIRS):
             self.registry.counter(event).inc(int(value))
 
     def close(self) -> None:

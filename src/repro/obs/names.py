@@ -54,6 +54,11 @@ ADC_OCCUPANCY = "cim.adc.occupancy"
 #: records each kernel's grid size while it is traced, never inside the
 #: compiled program; ``repro.obs.compiles`` counts it)
 CIM_GRID_STEPS = "cim.grid.steps"
+#: counter: bytes of the patch operands of the CIM convs traced
+#: (``kernels.cim_conv.tile_major_patches`` records each conv's
+#: (k_tiles, M, rows) operand while it is traced; ``repro.obs.compiles``
+#: counts it)
+CIM_PATCHES_BYTES = "cim.patches.bytes"
 
 # -- vision plane (recorded by repro.models.vit while it is traced) ---------
 
@@ -85,7 +90,9 @@ JIT_CACHE_MISSES = "jit.cache.misses"
 
 #: scope: activation codes and the dequant scale arithmetic of a CIM layer
 SCOPE_ACT_QUANT = "cim.act_quant"
-#: scope: stretched-kernel patch extraction (``ref.extract_conv_patches``)
+#: scope: stretched-kernel patch extraction: the deploy path's tile-major
+#: builder (``kernels.cim_conv.tile_major_patches``) and the oracle's
+#: (``ref.extract_conv_patches``)
 SCOPE_PATCHES = "cim.patches"
 #: scope: the deploy kernels' operand relayout — tile-major activations,
 #: scale slabs, the occupancy table, padding before the kernel and the

@@ -186,22 +186,70 @@ def test_layers_conv_specs_and_apply():
     assert dparams["w_digits"].dtype == jnp.int8
 
 
+#: (kernel, stride, padding, c_per_array, C_in, codes): 3x3 at stride 1
+#: and 2 under SAME, VALID and explicit asymmetric pads, 1x1 at stride 2,
+#: channels that leave the last array tile part-filled, and int8, uint8
+#: and float32 codes at the ends of their 8-bit ranges
+PATCH_CASES = [
+    (3, 1, "SAME", 14, 64, "int8"),
+    (3, 2, "SAME", 14, 64, "int8"),
+    (3, 2, "VALID", 14, 40, "uint8"),
+    (3, 1, ((2, 0), (1, 3)), 5, 12, "float32"),
+    (3, 2, "SAME", 3, 7, "float32"),
+    (1, 2, "SAME", 128, 200, "int8"),
+    (1, 1, "VALID", 4, 9, "float32"),
+]
+
+
+@pytest.mark.parametrize(
+    "k,stride,padding,cpa,c_in,codes", PATCH_CASES,
+    ids=[f"{k}x{k}s{st}-{p if isinstance(p, str) else 'pads'}-{ci}c-{d}"
+         for k, st, p, _, ci, d in PATCH_CASES])
+def test_tile_major_patches_bit_exact(k, stride, padding, cpa, c_in, codes):
+    """The deploy path's patch operand (one 0/1 convolution over the
+    tile-major code map) equals the oracle's stretched-kernel patches bit
+    for bit, in the code dtype: ``ref.extract_conv_patches`` with its tile
+    axis first and the output positions in (h', w', b) order."""
+    from repro.kernels import ref
+    from repro.kernels.cim_conv import tile_major_patches
+    kt = -(-c_in // cpa)
+    lo, hi = {"int8": (-128, 127), "uint8": (0, 255),
+              "float32": (-128, 255)}[codes]
+    a = jax.random.randint(jax.random.PRNGKey(k + stride), (3, 9, 11, c_in),
+                           lo, hi + 1)
+    a = a.at[0, 0, 0, :2].set(jnp.array([lo, hi])).astype(codes)
+    got = jax.jit(lambda x: tile_major_patches(x, k, k, stride, padding, kt,
+                                               cpa))(a)
+    p = ref.extract_conv_patches(a, k, k, stride, padding, kt, cpa)
+    want = jnp.transpose(p, (3, 1, 2, 0, 4)).reshape(kt, -1, k * k * cpa)
+    assert got.dtype == a.dtype and got.shape == want.shape
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 @pytest.mark.parametrize("k,stride", [(3, 1), (3, 2), (1, 2)])
 @pytest.mark.parametrize("psum_bits", [1, 6, None],
                          ids=["adc1", "adc6", "adc_free"])
 @pytest.mark.parametrize("store", ["int8", "nibble"])
 @pytest.mark.parametrize("skip", [False, True], ids=["dense", "skip"])
+@pytest.mark.parametrize("variation", [False, True],
+                         ids=["clean", "variation"])
 def test_conv_kernel_bit_exact_with_oracle(k, stride, psum_bits, store,
-                                           skip):
+                                           skip, variation):
     """The conv deploy kernels equal ``ref.cim_conv_ref`` (ADC-free,
     ``psum_bits=None``: the unquantized oracle) bit for bit: 128-row
     arrays of 14 channels x 9 taps (3x3) or 128 channels (1x1), 64
     output columns (half a lane block), M = 2*9*9 = 162 or 2*5*5 = 50
-    rows in one padded block, and dead planes for the occupancy skip."""
+    rows in one padded block, and dead planes for the occupancy skip.
+    Under one variation realization the float digits make the column
+    sums order-dependent, so the reference is the same matmul kernel fed
+    the oracle's patches (``ref.extract_conv_patches``) with the same
+    key: the conv path only builds the operand differently."""
     from repro.core.nibble import occupancy_map, pack_nibbles
     from repro.kernels import ref
-    from repro.kernels.cim_adc_free import cim_conv_adc_free_pallas
+    from repro.kernels.cim_adc_free import (cim_conv_adc_free_pallas,
+                                            cim_matmul_adc_free_pallas)
     from repro.kernels.cim_conv import cim_conv_pallas
+    from repro.kernels.cim_matmul import cim_matmul_pallas
     cpa = 128 // (k * k)
     c_in, c_out = 3 * cpa - 5, 64
     kt = -(-c_in // cpa)
@@ -215,18 +263,26 @@ def test_conv_kernel_bit_exact_with_oracle(k, stride, psum_bits, store,
     occ = occupancy_map(digits, conv=True) if skip else None
     s_p = 2.0 ** jax.random.randint(ks[2], (2, kt, c_out), 3, 8)
     deq = jax.random.uniform(ks[3], (2, kt, c_out), minval=0.01, maxval=0.1)
+    key, std = (jax.random.PRNGKey(5), 0.1) if variation else (None, None)
     geo = dict(kh=k, kw=k, stride=stride, padding="SAME", c_per_array=cpa)
+    a_t = ref.extract_conv_patches(a.astype(jnp.float32), k, k, stride,
+                                   "SAME", kt, cpa)
+    a2 = a_t.reshape(-1, kt, k * k * cpa)
     if psum_bits is None:
-        out = cim_conv_adc_free_pallas(a, stored, deq, None, None, occ,
+        out = cim_conv_adc_free_pallas(a, stored, deq, key, std, occ,
                                        interpret=True, **geo)
-        a_t = ref.extract_conv_patches(a.astype(jnp.float32), k, k, stride,
-                                       "SAME", kt, cpa)
-        expect = ref.cim_matmul_adc_free_ref(
-            a_t.reshape(-1, kt, k * k * cpa), flat, deq
-        ).reshape(a_t.shape[:3] + (c_out,))
+        expect = (cim_matmul_adc_free_pallas(
+            a2, stored, deq, key, std, occ, nibble_groups=k * k,
+            interpret=True) if variation
+            else ref.cim_matmul_adc_free_ref(a2, flat, deq))
+        expect = expect.reshape(a_t.shape[:3] + (c_out,))
     else:
-        out = cim_conv_pallas(a, stored, s_p, deq, None, None, occ,
+        out = cim_conv_pallas(a, stored, s_p, deq, key, std, occ,
                               psum_bits=psum_bits, interpret=True, **geo)
-        expect = ref.cim_conv_ref(a, flat, s_p, deq, psum_bits=psum_bits,
-                                  **geo)
+        expect = (cim_matmul_pallas(
+            a2, stored, s_p, deq, key, std, occ, psum_bits=psum_bits,
+            nibble_groups=k * k, interpret=True
+        ).reshape(a_t.shape[:3] + (c_out,)) if variation
+            else ref.cim_conv_ref(a, flat, s_p, deq, psum_bits=psum_bits,
+                                  **geo))
     np.testing.assert_array_equal(np.asarray(out), np.asarray(expect))

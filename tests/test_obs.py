@@ -192,6 +192,33 @@ def test_compile_watch_counts_cim_grid_steps():
     assert reg.counter(M.CIM_GRID_STEPS).value == steps
 
 
+def test_compile_watch_counts_cim_patch_bytes():
+    """Tracing a CIM conv adds the bytes of its patch operand to
+    ``cim.patches.bytes``: k_tiles * M * rows for int8 codes, four times
+    that for float32 codes. A second call with the same shapes reuses
+    the trace and adds nothing."""
+    from repro.kernels.cim_conv import cim_conv_pallas
+    from repro.obs import CompileWatch
+    reg = MetricsRegistry()
+    kt, cpa, n = 3, 5, 8                   # shapes no other test traces
+    rows, m = 9 * cpa, 2 * 4 * 6           # 3x3 stride 2 over 7x11
+    d = jnp.ones((2, kt, rows, n), jnp.int8)
+    sc = jnp.ones((2, kt, n))
+    geo = dict(kh=3, kw=3, stride=2, padding="SAME", c_per_array=cpa,
+               psum_bits=4, interpret=True)
+    watch = CompileWatch(reg)
+    try:
+        a = jnp.ones((2, 7, 11, 13), jnp.int8)
+        cim_conv_pallas(a, d, sc, sc, **geo)
+        assert reg.counter(M.CIM_PATCHES_BYTES).value == kt * m * rows
+        cim_conv_pallas(a, d, sc, sc, **geo)
+        assert reg.counter(M.CIM_PATCHES_BYTES).value == kt * m * rows
+        cim_conv_pallas(a.astype(jnp.float32), d, sc, sc, **geo)
+        assert reg.counter(M.CIM_PATCHES_BYTES).value == 5 * kt * m * rows
+    finally:
+        watch.close()
+
+
 # ---------------------------------------------------------------------------
 # ADC saturation collector
 # ---------------------------------------------------------------------------

@@ -16,6 +16,7 @@ runs this file loads the TPU compiler.
 """
 import base64
 import json
+import math
 import os
 import re
 
@@ -184,28 +185,98 @@ RESNET18_CONVS = [(56, 64, 64, 3, 1), (28, 128, 128, 3, 1),
                   (28, 128, 256, 1, 2), (14, 256, 512, 1, 2)]
 
 
+def _resnet18_conv(sharding, hw, c_in, c_out, k, stride):
+    """The compiled text of one ResNet-18 CIM conv at batch 128 on int4
+    nibble planes with the occupancy map, as the deploy path calls it,
+    and its (M, k_tiles, rows, c_per_array). Compiled once per shape in
+    the module (``_COMPILED``): two tests read each."""
+    cpa = min(ROWS // (k * k), c_in)
+    kt = -(-c_in // cpa)
+    rows = k * k * cpa
+    ho = -(-hw // stride)
+    key = (hw, c_in, c_out, k, stride)
+    if key not in _COMPILED:
+        a = _spec((128, hw, hw, c_in), jnp.int8, sharding)
+        d = _spec((S, kt, rows // 2, c_out), jnp.uint8, sharding)
+        sp = _spec((S, kt, c_out), jnp.float32, sharding)
+        occ = _spec((S, kt, c_out), jnp.uint8, sharding)
+        _COMPILED[key] = cim_conv_pallas.lower(
+            a, d, sp, sp, None, None, occ, kh=k, kw=k, stride=stride,
+            padding="SAME", c_per_array=cpa, psum_bits=6,
+            interpret=False).compile().as_text()
+    return _COMPILED[key], (128 * ho * ho, kt, rows, cpa)
+
+
+_COMPILED = {}
+_RESNET18_IDS = [f"{h}x{ci}-{co}_{k}x{k}s{st}"
+                 for h, ci, co, k, st in RESNET18_CONVS]
+
+
 @pytest.mark.parametrize("hw,c_in,c_out,k,stride", RESNET18_CONVS,
-                         ids=[f"{h}x{ci}-{co}_{k}x{k}s{st}"
-                              for h, ci, co, k, st in RESNET18_CONVS])
+                         ids=_RESNET18_IDS)
 def test_conv_resnet18_batch128(one_chip, hw, c_in, c_out, k, stride):
     """Each ResNet-18 CIM conv at batch 128 on int4 nibble planes with the
     occupancy map — the benchmark's shapes — is one ``cim_matmul`` call
     on the grid the chooser picks, with every array tile in one step."""
-    cpa = min(ROWS // (k * k), c_in)
-    kt = -(-c_in // cpa)
-    rows = k * k * cpa
-    a = _spec((128, hw, hw, c_in), jnp.int8, one_chip)
-    d = _spec((S, kt, rows // 2, c_out), jnp.uint8, one_chip)
-    sp = _spec((S, kt, c_out), jnp.float32, one_chip)
-    occ = _spec((S, kt, c_out), jnp.uint8, one_chip)
-    ho = -(-hw // stride)
-    _, _, tk = _assert_grid(
-        cim_conv_pallas.lower(a, d, sp, sp, None, None, occ, kh=k, kw=k,
-                              stride=stride, padding="SAME",
-                              c_per_array=cpa, psum_bits=6,
-                              interpret=False),
-        128 * ho * ho, c_out, kt, rows, rows // 2, jnp.int8, jnp.uint8)
+    text, (m, kt, rows, _) = _resnet18_conv(one_chip, hw, c_in, c_out, k,
+                                            stride)
+    (name, grid, vmem), = _kernel_calls(text)
+    bm, bn, tk = block_shape(m, c_out, kt, rows, rows // 2, jnp.int8,
+                             jnp.uint8, n_cols=2)
+    assert re.fullmatch(r"cim_matmul(\.\d+)?", name), name
+    assert grid == (-(-m // bm), -(-c_out // bn), S, -(-kt // tk)), grid
+    assert vmem == VMEM_LIMIT
     assert tk == kt
+
+
+def _computations(text):
+    """{computation name: [(instruction name, element count, opcode,
+    op_name, the line)]} of a compiled module."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) .*\{\s*$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+            continue
+        ins = re.match(r"\s+(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\]\S* "
+                       r"([\w\-]+)\(", line)
+        if ins and cur is not None:
+            count = math.prod(int(d) for d in ins.group(2).split(",")
+                              if d)
+            op = re.search(r'op_name="([^"]*)"', line)
+            cur.append((ins.group(1), count, ins.group(3),
+                        op.group(1) if op else "", line))
+    return comps
+
+
+@pytest.mark.parametrize("hw,c_in,c_out,k,stride", RESNET18_CONVS,
+                         ids=_RESNET18_IDS)
+def test_conv_resnet18_patches_written_once(one_chip, hw, c_in, c_out, k,
+                                            stride):
+    """The patch operand of each ResNet-18 CIM conv at batch 128 is built
+    without a loop and written once: no ``while`` anywhere, and among the
+    program's own instructions of the operand's size (k_tiles * M *
+    rows elements) only the builder's convolution fusion (a strided
+    slice for 1x1) and at most one copy, each under ``cim.patches``."""
+    text, (m, kt, rows, _) = _resnet18_conv(one_chip, hw, c_in, c_out, k,
+                                            stride)
+    comps = _computations(text)
+    assert not [i for instrs in comps.values() for i in instrs
+                if i[2] == "while"]
+    entry = re.search(r"^ENTRY %([\w.\-]+) ", text, re.M).group(1)
+    big = [i for i in comps[entry]
+           if i[1] == kt * m * rows and i[2] not in ("bitcast", "parameter")]
+    builds = [i for i in big if i[2] != "copy"]
+    assert len(builds) == 1 and len(big) <= 2, [i[:3] for i in big]
+    (_, _, opcode, _, line), = builds
+    want = "slice" if k == 1 else "convolution"
+    if opcode == "fusion":
+        called = re.search(r"calls=%([\w.\-]+)", line).group(1)
+        assert any(i[2] == want for i in comps[called]), line
+    else:
+        assert opcode == want, line
+    for _, _, _, op_name, line in big:
+        assert "/cim.patches/" in op_name, line
 
 
 @pytest.mark.parametrize("dtype", [jnp.int8, jnp.uint8],
